@@ -14,18 +14,19 @@ import jax
 
 from benchmarks.common import Row, emit
 from benchmarks.hgnn_setup import build, stage_fns
-from repro.core.characterize import HBM_BW, PEAK_FLOPS, analyze_hlo_text
+from repro.core.characterize import analyze_hlo_text, peaks
 
 CASES = [("han", "imdb"), ("han", "dblp"), ("rgcn", "imdb"), ("magnn", "imdb")]
 CLASSES = ("DM", "TB", "EW", "DR")
 
 
 def class_times(rep):
+    pk = peaks()  # the device's published peaks (CPU hosts: the v5e model)
     out = {}
     for c in CLASSES:
         fl = rep["flops_by_class"].get(c, 0.0)
         by = rep["hbm_bytes_by_class"].get(c, 0.0)
-        out[c] = max(fl / PEAK_FLOPS, by / HBM_BW)
+        out[c] = max(fl / pk["flops"], by / pk["hbm_bw"])
     return out
 
 

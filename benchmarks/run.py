@@ -601,6 +601,9 @@ def write_bench_json(results: dict) -> None:
 def main() -> None:
     import importlib
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = sys.argv[1:]
     check = "--check" in argv
     only = [a for a in argv if a != "--check"] or None

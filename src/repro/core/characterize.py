@@ -16,7 +16,8 @@ walks it with a call-graph-aware cost model:
     the ``known_trip_count`` XLA records in backend_config — this is what
     ``compiled.cost_analysis()`` gets wrong (it counts loop bodies once).
 
-Outputs the three roofline terms (v5e constants) per the brief:
+Outputs the three roofline terms against the device's published peaks
+(``PEAKS``, keyed by ``device_kind``; TPU v5e shown):
     compute    = FLOPs / (chips x 197 TFLOP/s)
     memory     = HBM bytes / (chips x 819 GB/s)
     collective = collective bytes / (chips x 50 GB/s/link)
@@ -31,10 +32,30 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-# ---- TPU v5e hardware constants (per chip) ----
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # bytes/s
-LINK_BW = 50e9  # bytes/s per ICI link (conservative: 1 link)
+# ---- Per-chip peaks, keyed by JAX ``device_kind`` ----
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 and
+# 819 GB/s HBM per chip.  link_bw is a conservative one-ICI-link 50 GB/s.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+# HLO compiled on a host without a TPU is costed as if it ran on this chip.
+CPU_MODEL_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: Optional[str] = None) -> Dict:
+    """Peaks of ``device_kind`` (default: JAX's first device), with the kind
+    they belong to under ``"device_kind"``.  A kind missing from ``PEAKS``
+    raises; a non-TPU default device gets the ``CPU_MODEL_KIND`` model."""
+    if device_kind is None:
+        import jax
+
+        dev = jax.devices()[0]
+        device_kind = (dev.device_kind if dev.platform == "tpu"
+                       else CPU_MODEL_KIND)
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add it to PEAKS with its source")
+    return {"device_kind": device_kind, **PEAKS[device_kind]}
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -599,14 +620,17 @@ def model_flops(cfg, shape, n_total: float, n_active: float) -> float:
     return 2.0 * n_active * shape.global_batch  # decode: 1 token / sequence
 
 
-def roofline(per_device: Dict, n_chips: int, model_fl: float) -> Dict:
-    t_c = per_device["total_flops"] / PEAK_FLOPS
-    t_m = per_device["total_hbm_bytes"] / HBM_BW
-    t_l = per_device["collective_bytes"] / LINK_BW
+def roofline(per_device: Dict, n_chips: int, model_fl: float,
+             device_kind: Optional[str] = None) -> Dict:
+    pk = peaks(device_kind)
+    t_c = per_device["total_flops"] / pk["flops"]
+    t_m = per_device["total_hbm_bytes"] / pk["hbm_bw"]
+    t_l = per_device["collective_bytes"] / pk["link_bw"]
     bound = max((t_c, "compute"), (t_m, "memory"), (t_l, "collective"))[1]
     t_step = max(t_c, t_m, t_l)
     model_fl_dev = model_fl / n_chips
     return {
+        "peaks_of": pk["device_kind"],
         "compute_s": t_c,
         "memory_s": t_m,
         "collective_s": t_l,
@@ -616,8 +640,9 @@ def roofline(per_device: Dict, n_chips: int, model_fl: float) -> Dict:
         "model_flops_per_device": model_fl_dev,
         "useful_flops_ratio": model_fl_dev / per_device["total_flops"]
         if per_device["total_flops"] else 0.0,
-        "mfu_proxy": model_fl_dev / (t_step * PEAK_FLOPS) if t_step else 0.0,
-        "roofline_fraction": (model_fl_dev / PEAK_FLOPS) / t_step if t_step else 0.0,
+        "mfu_proxy": model_fl_dev / (t_step * pk["flops"]) if t_step else 0.0,
+        "roofline_fraction": ((model_fl_dev / pk["flops"]) / t_step
+                              if t_step else 0.0),
     }
 
 

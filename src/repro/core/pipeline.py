@@ -53,6 +53,11 @@ from repro.dist.sharding import BATCH, MODEL
 
 _ACT = {None: lambda x: x, "elu": jax.nn.elu, "relu": jax.nn.relu}
 
+# Features, weights and kernels are f32, and so is every matmul of the
+# forward: on TPU, XLA's default precision runs an f32 dot as a single bf16
+# pass, which a float32 reference at f32 tolerance rejects.
+MATMUL_PRECISION = "highest"
+
 
 def _kops():
     """Kernel dispatch goes through the module attribute so tests can
@@ -815,17 +820,18 @@ class StageGraphExecutor:
         features every layer over the graph-invariant halo maps."""
         plan = self.plan
         state = out = None
-        for l, lp in enumerate(plan.layers):
-            p_l = self._layer_params(params, l)
-            h = (self.fp(params, batch) if l == 0
-                 else self._fp_hidden(lp, p_l, state))
-            if plan.partition is not None:
-                h = self.gather_halo(batch, h)
-            z = self.na(p_l, batch, h)
-            out = self.sa(p_l, batch, z)
-            if l + 1 < plan.n_layers:
-                state = self._handoff(lp, batch, h, out)
-        return self.head(params, out, batch)
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            for l, lp in enumerate(plan.layers):
+                p_l = self._layer_params(params, l)
+                h = (self.fp(params, batch) if l == 0
+                     else self._fp_hidden(lp, p_l, state))
+                if plan.partition is not None:
+                    h = self.gather_halo(batch, h)
+                z = self.na(p_l, batch, h)
+                out = self.sa(p_l, batch, z)
+                if l + 1 < plan.n_layers:
+                    state = self._handoff(lp, batch, h, out)
+            return self.head(params, out, batch)
 
     # ------------------------------------------------------------------
     # the async stage-graph schedule (plan.schedule)
@@ -1017,7 +1023,8 @@ class StageGraphExecutor:
         def emit(name, key, fn, args):
             return win.admit(name, self._ovjit(key, fn)(*args))
 
-        out = self._walk_schedule(params, batch, emit)
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            out = self._walk_schedule(params, batch, emit)
         win.drain()
         self.last_dispatch = {
             "dispatched": list(win.admitted),

@@ -13,7 +13,7 @@ Two modules:
   the metapath-aware edge-cut partitioner (per-type vertex assignment,
   halo/ghost-vertex index maps, per-partition relabeling) and the
   ``gather_halo`` feature exchange (shard_map over the BATCH axes).
-  Imported lazily by the executor — it pulls in jax.experimental.
+  Imported lazily by the executor.
 """
 from repro.dist.sharding import (  # noqa: F401
     BATCH,

@@ -46,7 +46,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import BATCH, current_mesh, shard
@@ -232,11 +231,11 @@ def gather_halo(h_own: jax.Array, halo_src: jax.Array,
                 h_all = jax.lax.all_gather(h, ax, axis=0, tiled=True)
                 return h_all.reshape((k * n,) + tail)[idx]
 
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(ax, *([None] * (len(tail) + 1))), P(ax, None)),
                 out_specs=P(ax, *([None] * (len(tail) + 1))),
-                check_rep=False,
+                check_vma=False,
             )(h_own, halo_src)
     flat = h_own.reshape((k * n,) + tail)
     out = flat[halo_src]

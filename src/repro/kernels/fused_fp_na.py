@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import streaming
+from repro.kernels import gather, streaming
 from repro.kernels.segment_spmm import _accumulate, _mean
 
 
@@ -40,14 +40,18 @@ def _write_partial(out_ref, part, fi):
         out_ref[...] = (out_ref[...] + part).astype(out_ref.dtype)
 
 
-def _kernel(nbr_ref, mask_ref, x_ref, w_ref, out_ref, *, mean: bool):
+def _kernel(nbr_ref, mask_ref, x_ref, w_ref, out_ref, *, mean: bool,
+            block_m: int):
     fi = pl.program_id(1)  # feature-dim tile index
     nbr = nbr_ref[...]
     mask = mask_ref[...]
     w = w_ref[...]  # [BF, D]
-    acc = jnp.zeros((nbr.shape[0], x_ref.shape[1]), jnp.float32)
-    acc = _mean(_accumulate(acc, nbr, mask, x_ref[...], 0), mask, mean)
-    part = acc.astype(w.dtype) @ w  # MXU: fused projection of the aggregate
+    acc = gather.walk_chunks(
+        x_ref, block_m,
+        lambda acc, chunk, lo: _accumulate(acc, nbr, mask, chunk, lo),
+        jnp.zeros((nbr.shape[0], x_ref.shape[1]), jnp.float32))
+    # MXU: fused projection of the aggregate
+    part = gather.matmul(_mean(acc, mask, mean).astype(w.dtype), w)
     _write_partial(out_ref, part, fi)
 
 
@@ -84,7 +88,7 @@ def _stream_kernel(sched_ref, count_ref, nbr_ref, mask_ref, x_ref, w_ref,
     acc0 = jnp.zeros((nbr.shape[0], block_f), jnp.float32)
     acc = _mean(jax.lax.fori_loop(0, nc, body, acc0), mask, mean)
     w = w_ref[...]
-    part = acc.astype(w.dtype) @ w
+    part = gather.matmul(acc.astype(w.dtype), w)
     _write_partial(out_ref, part, fi)
 
 
@@ -117,25 +121,31 @@ def fused_fp_na(
     out_shape = jax.ShapeDtypeStruct((n + n_pad, d), w.dtype)
 
     resident = block_m == 0 and streaming.table_fits_vmem(
-        m, block_f * x_src.dtype.itemsize, vmem_budget)
+        m, block_f, x_src.dtype.itemsize, vmem_budget)
     if resident:
+        block_m = streaming.chunk_rows(m)
+        x_src = streaming.pad_rows(x_src, block_m)
+        m_pad = x_src.shape[0]
         out = pl.pallas_call(
-            functools.partial(_kernel, mean=mean),
+            functools.partial(_kernel, mean=mean, block_m=block_m),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_n, k), lambda i, fi: (i, 0)),
                 pl.BlockSpec((block_n, k), lambda i, fi: (i, 0)),
-                pl.BlockSpec((m, block_f), lambda i, fi: (0, fi)),
+                pl.BlockSpec((m_pad, block_f), lambda i, fi: (0, fi)),
                 pl.BlockSpec((block_f, d), lambda i, fi: (fi, 0)),
             ],
             out_specs=pl.BlockSpec((block_n, d), lambda i, fi: (i, 0)),
             out_shape=out_shape,
+            compiler_params=streaming.resident_params(
+                streaming.table_vmem_bytes(m_pad, block_f,
+                                           x_src.dtype.itemsize)),
             interpret=interpret,
         )(nbr, mask, x_src, w)
         return out[:n]
 
     if block_m == 0:
-        block_m = 512
+        block_m = streaming.CHUNK_ROWS
     block_m = min(block_m, max(m, 1))
     x_src = streaming.pad_rows(x_src, block_m)
     n_chunks = x_src.shape[0] // block_m
@@ -147,7 +157,7 @@ def fused_fp_na(
         in_specs=[
             pl.BlockSpec((block_n, k), lambda i, fi, *_: (i, 0)),
             pl.BlockSpec((block_n, k), lambda i, fi, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # raw table stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # raw table stays in HBM
             pl.BlockSpec((block_f, d), lambda i, fi, *_: (fi, 0)),
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, fi, *_: (i, 0)),

@@ -1,11 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Dispatch policy:
-  * On TPU (``use_pallas=True`` in configs) the Pallas kernels run compiled.
-  * On CPU (this container, and the multi-pod dry-run) Pallas TPU custom
-    calls cannot compile, so wrappers either run ``interpret=True`` (tests)
-    or fall back to the pure-jnp reference (dry-run lowering), which is what
-    the roofline analysis reads.
+  * ``use_pallas=False`` runs the pure-jnp reference (``kernels/ref.py``) —
+    the XLA path, on any backend.
+  * ``use_pallas=True`` runs the Pallas kernel: compiled on a TPU backend,
+    in the interpreter with ``interpret=True`` (CPU tests).  Anywhere else
+    it raises — a run that asked for the kernels never gets the reference
+    in their place.
 """
 from __future__ import annotations
 
@@ -31,10 +32,22 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _pallas(use_pallas: bool, interpret: bool) -> bool:
+    """Does a wrapper run its Pallas kernel?  Raises for ``use_pallas=True``
+    on a backend that cannot compile it without ``interpret=True``."""
+    if not use_pallas:
+        return False
+    if interpret or _on_tpu():
+        return True
+    raise RuntimeError(
+        "use_pallas=True needs a TPU backend or interpret=True; the "
+        f"{jax.default_backend()!r} backend cannot compile Pallas TPU kernels")
+
+
 @functools.partial(jax.jit, static_argnames=("mean", "use_pallas", "interpret"))
 def segment_spmm(h_src, nbr, mask, mean: bool = True, use_pallas: bool = False,
                  interpret: bool = False):
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _spmm.segment_spmm(h_src, nbr, mask, mean=mean, interpret=interpret)
     return ref.segment_spmm(h_src, nbr, mask, mean=mean)
 
@@ -42,14 +55,14 @@ def segment_spmm(h_src, nbr, mask, mean: bool = True, use_pallas: bool = False,
 @functools.partial(jax.jit, static_argnames=("mean", "use_pallas", "interpret"))
 def fused_fp_na(x_src, w, nbr, mask, mean: bool = True, use_pallas: bool = False,
                 interpret: bool = False):
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _ffn.fused_fp_na(x_src, w, nbr, mask, mean=mean, interpret=interpret)
     return ref.fused_fp_na(x_src, w, nbr, mask, mean=mean)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def semantic_attention(z, w, b, q, use_pallas: bool = False, interpret: bool = False):
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _sem.semantic_attention(z, w, b, q, interpret=interpret)
     return ref.semantic_attention(z, w, b, q)
 
@@ -59,7 +72,7 @@ def semantic_attention(z, w, b, q, use_pallas: bool = False, interpret: bool = F
 )
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     use_pallas: bool = False, interpret: bool = False):
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                    interpret=interpret)
     return ref.mha_attention(q, k, v, causal=causal, window=window)
@@ -68,7 +81,7 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def decode_attention(q, k, v, kv_len, use_pallas: bool = False,
                      interpret: bool = False):
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _dec.decode_attention(q, k, v, kv_len, interpret=interpret)
     return ref.decode_attention(q, k, v, kv_len)
 
@@ -80,7 +93,7 @@ def cached_gather(table, hot, idx, use_pallas: bool = False,
     extended pool ``concat(table, table[hot])`` with the cache section
     VMEM-resident on the Pallas path (kernels/feature_cache.py).  Indices
     ``>= len(table)`` hit the cache; the rest gather from HBM."""
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _fc.cached_gather(table, hot, idx, interpret=interpret)
     return ref.cached_gather(table, hot, idx)
 
@@ -96,7 +109,7 @@ def gat_aggregate(p: Dict, h_dst, h_src, nbr, mask, use_pallas: bool = False,
     tile is gathered exactly once and every head rides the same gather.
     Large source tables stream from HBM instead of falling back to the ref.
     """
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _gat.gat_na(p, h_dst, h_src, nbr, mask, interpret=interpret)
     return ref.gat_na(p, h_dst, h_src, nbr, mask)
 
@@ -107,7 +120,7 @@ def gat_aggregate_stacked(p_stacked: Dict, h_dst, h_src, nbr, mask,
     """Stacked form: ``nbr/mask [P, N, K]``, params ``[P, H, Dh]`` — the whole
     metapath stack (HAN's inter-subgraph parallelism) is ONE kernel launch
     (the stack dim rides the Pallas grid), not P launches of H kernels."""
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _gat.gat_na(p_stacked, h_dst, h_src, nbr, mask,
                            interpret=interpret)
     return ref.gat_na(p_stacked, h_dst, h_src, nbr, mask)
@@ -122,7 +135,7 @@ def gat_aggregate_stacked_fused_sa(p_stacked: Dict, h_dst, h_src, nbr, mask,
     each ``z`` tile is still in VMEM, so SA never re-reads the ``[P, N, D]``
     stack for its scores.  Returns ``(z [P, N, H, Dh] elu-activated, w [P])``.
     """
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _gat.gat_na(p_stacked, h_dst, h_src, nbr, mask,
                            interpret=interpret, sem=sem)
     return ref.gat_na_fused_sa(p_stacked, h_dst, h_src, nbr, mask,
@@ -134,6 +147,6 @@ def semantic_combine(z, beta, use_pallas: bool = False,
                      interpret: bool = False):
     """SA pass 2 only (the fused-epilogue path's remaining work): weighted
     combine ``sum_p beta_p z_p`` — exactly one read of the stack."""
-    if use_pallas and (_on_tpu() or interpret):
+    if _pallas(use_pallas, interpret):
         return _sem.semantic_combine(z, beta, interpret=interpret)
     return jnp.einsum("p,pnd->nd", beta, z)

@@ -12,9 +12,12 @@ mask tile live in VMEM.  The source feature table has two paths:
 * **resident** — small tables (HGNN latent: N x D ~ 4k x 64 ~ 1 MB) are one
   whole-table BlockSpec; the Pallas pipeline keeps them in VMEM across tiles.
 * **streaming** — larger tables stay in HBM; a scalar-prefetched chunk
-  schedule drives double-buffered ``make_async_copy`` DMAs and each chunk is
-  gathered via an in-chunk mask (see ``kernels/streaming.py``).  No more
-  silent fallback to the XLA ref for big graphs.
+  schedule drives double-buffered ``make_async_copy`` DMAs (see
+  ``kernels/streaming.py``).
+
+Either way the table is consumed in ``block_m``-row chunks: per chunk, the
+K neighbor slots fold into one edge-weighted ``[BN, BM]`` selection tile and
+a single MXU matmul reduces the chunk (``kernels/gather.py``).
 """
 from __future__ import annotations
 
@@ -25,20 +28,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import streaming
+from repro.kernels import gather, streaming
 
 
 def _accumulate(acc, nbr, mask, hbuf, lo):
-    """Masked K-step gather-reduce of one source chunk into ``acc``."""
-    bm = hbuf.shape[0]
-    in_chunk = (nbr >= lo) & (nbr < lo + bm)
-    loc = jnp.where(in_chunk, nbr - lo, 0)
-    w = mask.astype(jnp.float32) * in_chunk.astype(jnp.float32)
-    k = nbr.shape[1]
-    for j in range(k):  # K-step reduction tree
-        rows = jnp.take(hbuf, loc[:, j], axis=0)
-        acc = acc + rows.astype(jnp.float32) * w[:, j][:, None]
-    return acc
+    """Masked K-step gather-reduce of one source chunk into ``acc``; ids
+    outside the chunk ``[lo, lo+BM)`` add nothing."""
+    return acc + gather.weighted_rows(hbuf[:, :acc.shape[1]], nbr - lo, mask)
 
 
 def _mean(acc, mask, mean: bool):
@@ -49,11 +45,14 @@ def _mean(acc, mask, mean: bool):
     return acc
 
 
-def _kernel(nbr_ref, mask_ref, hsrc_ref, out_ref, *, mean: bool):
+def _kernel(nbr_ref, mask_ref, hsrc_ref, out_ref, *, mean: bool,
+            block_m: int):
     nbr = nbr_ref[...]  # [BN, K] int32
     mask = mask_ref[...]  # [BN, K]
-    acc = jnp.zeros((nbr.shape[0], hsrc_ref.shape[1]), jnp.float32)
-    acc = _accumulate(acc, nbr, mask, hsrc_ref[...], 0)
+    acc = gather.walk_chunks(
+        hsrc_ref, block_m,
+        lambda acc, chunk, lo: _accumulate(acc, nbr, mask, chunk, lo),
+        jnp.zeros((nbr.shape[0], out_ref.shape[1]), jnp.float32))
     out_ref[...] = _mean(acc, mask, mean).astype(out_ref.dtype)
 
 
@@ -111,26 +110,31 @@ def segment_spmm(
     out_shape = jax.ShapeDtypeStruct((n + n_pad, d), h_src.dtype)
 
     resident = block_m == 0 and streaming.table_fits_vmem(
-        m, d * h_src.dtype.itemsize, vmem_budget)
+        m, d, h_src.dtype.itemsize, vmem_budget)
     if resident:
+        block_m = streaming.chunk_rows(m)
+        h_src = streaming.pad_rows(h_src, block_m)
+        m_pad = h_src.shape[0]
         out = pl.pallas_call(
-            functools.partial(_kernel, mean=mean),
+            functools.partial(_kernel, mean=mean, block_m=block_m),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_n, k), lambda i: (i, 0)),
                 pl.BlockSpec((block_n, k), lambda i: (i, 0)),
-                pl.BlockSpec((m, d), lambda i: (0, 0)),  # whole feature table
+                pl.BlockSpec((m_pad, d), lambda i: (0, 0)),  # whole table
             ],
             out_specs=pl.BlockSpec((block_n, d), lambda i: (i, 0)),
             out_shape=out_shape,
+            compiler_params=streaming.resident_params(
+                streaming.table_vmem_bytes(m_pad, d, h_src.dtype.itemsize)),
             interpret=interpret,
         )(nbr, mask, h_src)
         return out[:n]
 
     if block_m == 0:
-        block_m = 512
+        block_m = streaming.CHUNK_ROWS
     block_m = min(block_m, max(m, 1))
-    h_src = streaming.pad_rows(h_src, block_m)
+    h_src = streaming.pad_lanes(streaming.pad_rows(h_src, block_m))
     n_chunks = h_src.shape[0] // block_m
     sched, count = streaming.chunk_schedule(nbr, mask, block_n, n_chunks,
                                             block_m)
@@ -140,11 +144,11 @@ def segment_spmm(
         in_specs=[
             pl.BlockSpec((block_n, k), lambda i, *_: (i, 0)),
             pl.BlockSpec((block_n, k), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # h_src stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # h_src stays in HBM
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, *_: (i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, block_m, d), h_src.dtype),  # double buffer
+            pltpu.VMEM((2, block_m, h_src.shape[1]), h_src.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )

@@ -32,16 +32,50 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
-# Source tables at or under this many bytes stay whole-in-VMEM (resident
-# BlockSpec path); larger ones stream.  Half of a v5e core's 16 MB VMEM,
-# leaving room for the row tile, schedule buffers and double buffers.
+# Source tables whose VMEM footprint is at most this many bytes stay whole in
+# VMEM (resident BlockSpec path); larger ones stream.  The footprint counts
+# the lane padding of narrow rows (a 64-wide f32 row occupies 128 lanes).
+# 16 MiB is v5e's default *scoped* VMEM limit, not its VMEM size (128 MiB
+# per core): the pipeline double-buffers the resident block, so resident
+# kernels raise the scoped limit to ``2 * budget + TILE_VMEM``
+# (``resident_params``).
 VMEM_TABLE_BUDGET = 8 * 1024 * 1024
+# Scoped VMEM for everything a kernel holds besides its resident table:
+# double-buffered row tiles, one-hot selection tiles, accumulators.
+TILE_VMEM = 16 * 1024 * 1024
+# Rows per one-hot selection chunk (kernels/gather.py): at block_n=128 the
+# f32 selection tile is 256 KB.
+CHUNK_ROWS = 512
 
 
-def table_fits_vmem(m: int, row_bytes: int, budget: int = VMEM_TABLE_BUDGET) -> bool:
-    """Static (trace-time) residency decision for an ``[m, ...]`` table."""
-    return m * row_bytes <= budget
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def table_vmem_bytes(m: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of an ``[m, cols]`` block: rows pad to 8 sublanes,
+    columns to 128 lanes."""
+    return _round_up(max(m, 1), 8) * _round_up(cols, 128) * itemsize
+
+
+def table_fits_vmem(m: int, cols: int, itemsize: int,
+                    budget: int = VMEM_TABLE_BUDGET) -> bool:
+    """Static (trace-time) residency decision for an ``[m, cols]`` table."""
+    return table_vmem_bytes(m, cols, itemsize) <= budget
+
+
+def resident_params(table_bytes: int) -> pltpu.CompilerParams:
+    """Scoped-VMEM limit for a kernel holding a double-buffered resident
+    block of ``table_bytes`` (``table_vmem_bytes``) plus its tiles."""
+    return pltpu.CompilerParams(vmem_limit_bytes=2 * table_bytes + TILE_VMEM)
+
+
+def chunk_rows(m: int) -> int:
+    """Chunk size for walking a resident ``m``-row table: ``CHUNK_ROWS``, or
+    the whole table rounded up to a sublane multiple when it is smaller."""
+    return min(CHUNK_ROWS, _round_up(max(m, 1), 8))
 
 
 def chunk_schedule(
@@ -73,6 +107,17 @@ def chunk_schedule(
     count = touched.sum(axis=1).astype(jnp.int32)
     sched = jnp.where(sched >= n_chunks, 0, sched).astype(jnp.int32)
     return sched, count
+
+
+def pad_lanes(x: jax.Array) -> jax.Array:
+    """Zero-pad the minor dim of an HBM array to a multiple of 128 lanes:
+    Mosaic slices an HBM ref for a DMA only along whole lane tiles, so a
+    64-wide table cannot be chunked as it is.  Kernels drop the pad columns
+    after the copy."""
+    pad = (-x.shape[-1]) % 128
+    if pad:
+        x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+    return x
 
 
 def pad_rows(x: jax.Array, multiple: int) -> jax.Array:

@@ -199,6 +199,11 @@ def run_hgnn_serve(args, cfg: HGNNConfig, hg, built: BuiltHGNNInfer) -> None:
           f"degrade_steps={rs['degrade_steps']} "
           f"max_degrade_level={rs['max_degrade_level']} "
           f"failovers={rs['partition_failovers']}")
+    if rs["failed_requests"] and args.inject_faults is None:
+        # with no fault schedule, a FAILED request is a real fault (compile
+        # refusal, device OOM, sampler error): the run must not pass
+        raise SystemExit(f"{rs['failed_requests']} of {len(reqs)} requests "
+                         "FAILED with no --inject-faults schedule")
     if "prefetch" in st:
         pf = st["prefetch"]
         print(f"  prefetch: issued={pf['issued']} hits={pf['hits']} "
@@ -307,6 +312,9 @@ def run_hgnn(args) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--reduced", action="store_true")

@@ -69,6 +69,21 @@ def test_roofline_terms_and_bound():
     assert r["bound"] == "collective"
 
 
+def test_peaks_keyed_by_device_kind():
+    """One table keyed by device_kind; an unknown kind raises; a CPU host
+    costs its HLO against the named v5e model."""
+    v5e = ch.peaks("TPU v5 lite")
+    assert (v5e["flops"], v5e["hbm_bw"]) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="TPU v9"):
+        ch.peaks("TPU v9")
+    assert ch.peaks()["device_kind"] == ch.CPU_MODEL_KIND == "TPU v5 lite"
+    per_dev = {"total_flops": 1.0, "total_hbm_bytes": 1.0,
+               "collective_bytes": 0.0}
+    assert ch.roofline(per_dev, 1, 0.0)["peaks_of"] == "TPU v5 lite"
+    with pytest.raises(ValueError):
+        ch.roofline(per_dev, 1, 0.0, device_kind="TPU v9")
+
+
 def test_model_flops_formulas():
     from repro.configs import get_config
     from repro.configs.base import SHAPES

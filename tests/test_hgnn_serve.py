@@ -218,3 +218,37 @@ def test_cache_state_survives_partition_failover_bit_exact(tiny_hg):
     for t in e1.caches:
         assert e1.caches[t].resident == e2.caches[t].resident
         assert e1.caches[t].pinned == set() == e2.caches[t].pinned
+
+
+@pytest.mark.parametrize("fault", [False, True], ids=["healthy", "sampler-fault"])
+def test_serve_cli_exits_nonzero_when_a_request_fails(tiny_hg, monkeypatch,
+                                                      fault):
+    """With no --inject-faults schedule, a FAILED request (here a sampler
+    that errors on every real batch) makes the serve run exit non-zero
+    instead of printing its counters and passing."""
+    import argparse
+
+    from repro.launch.serve import build_hgnn_infer, run_hgnn_serve
+
+    _tiny_tables()
+    cfg = HGNNConfig(model="han", dataset="tiny", hidden=16, n_heads=4,
+                     n_classes=3, max_degree=48, fused=True, fanout=8)
+    if fault:
+        orig = HGNNSampler.sample
+
+        def failing(self, targets, *a, **kw):
+            if len(targets):  # warmup's all-pad batches still sample
+                raise RuntimeError("sampler fault")
+            return orig(self, targets, *a, **kw)
+
+        monkeypatch.setattr(HGNNSampler, "sample", failing)
+    args = argparse.Namespace(
+        max_queue=None, deadline_ms=None, slo_ms=None, slo_signal="observed",
+        inject_faults=None, slots=2, slot_targets=2, requests=4,
+        characterize=False)
+    built = build_hgnn_infer(cfg, tiny_hg)
+    if fault:
+        with pytest.raises(SystemExit, match="4 of 4 requests FAILED"):
+            run_hgnn_serve(args, cfg, tiny_hg, built)
+    else:
+        run_hgnn_serve(args, cfg, tiny_hg, built)

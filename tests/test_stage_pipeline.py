@@ -146,6 +146,9 @@ def test_executor_parity_matrix(tiny_hg, monkeypatch, model, ref_kw, var_kw,
     if wrapper is not None:
         (_force_streaming if mode == "streaming"
          else _force_interpret)(monkeypatch, wrapper)
+    if wrapper == "gat_aggregate_stacked_fused_sa":
+        # SA pass 2 of the same plan runs the semantic_combine kernel
+        _force_interpret(monkeypatch, "semantic_combine")
     cfg_var = _cfg(model, **var_kw)
     m_var = get_model(cfg_var)
     b_var = m_var.prepare(tiny_hg)
