@@ -24,7 +24,8 @@ defaults (hidden 64, 8 heads, K=64).  Graph and weights come from ``--seed``.
 
 Each full-graph phase is checked against a float32 reference of the same
 model on the same (degree-capped) graph: the csr layout on plain
-``jax.numpy`` under ``jax.default_matmul_precision("highest")``.  The
+``jax.numpy``, whose dots the executor runs at ``MATMUL_PRECISION``
+(``"highest"``, ``kernels/gather.py``) like every HGNN forward.  The
 tolerance is ``RTOL`` times the reference's largest |logit|; phase (a) also
 shows that bf16-rounded operands miss it, so a bf16 matmul cannot pass.
 
@@ -105,10 +106,8 @@ class Reference:
         self.fn = jax.jit(self.model.forward)
 
     def __call__(self, batch: dict, params=None):
-        with jax.default_matmul_precision("highest"):
-            out = self.fn(self.params if params is None else params,
-                          csr_batch(batch))
-        return np.asarray(out)
+        return np.asarray(self.fn(self.params if params is None else params,
+                                  csr_batch(batch)))
 
 
 def max_err(got, want) -> float:
@@ -244,6 +243,7 @@ def kernel_paths(seed: int) -> None:
     """(k): kernels and paths that (a)-(d) do not reach, at IMDB widths."""
     from repro.kernels import (feature_cache, fused_fp_na, gat_na, ref,
                                segment_spmm, semantic_attn)
+    from repro.kernels.gather import MATMUL_PRECISION
 
     rng = np.random.default_rng(seed)
     n, heads, dh, k, hs = 4278, 8, 8, 64, 128
@@ -294,7 +294,7 @@ def kernel_paths(seed: int) -> None:
         for _ in range(ITERS):
             got = jax.block_until_ready(compiled(*args))
         ms = (time.perf_counter() - t0) / ITERS * 1e3
-        with jax.default_matmul_precision("highest"):
+        with jax.default_matmul_precision(MATMUL_PRECISION):
             want = np.asarray(jax.jit(oracle)(*args))
         got = np.asarray(got)
         has_kernel = "tpu_custom_call" in compiled.as_text()

@@ -50,13 +50,9 @@ import numpy as np
 from repro.core import semantics, stages
 from repro.core.plan import StagePlan
 from repro.dist.sharding import BATCH, MODEL
+from repro.kernels.gather import MATMUL_PRECISION
 
 _ACT = {None: lambda x: x, "elu": jax.nn.elu, "relu": jax.nn.relu}
-
-# Features, weights and kernels are f32, and so is every matmul of the
-# forward: on TPU, XLA's default precision runs an f32 dot as a single bf16
-# pass, which a float32 reference at f32 tolerance rejects.
-MATMUL_PRECISION = "highest"
 
 
 def _kops():

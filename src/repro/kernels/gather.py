@@ -6,9 +6,9 @@ NA kernels (``gat_na``, ``segment_spmm``, ``fused_fp_na``, ``cached_gather``)
 therefore select rows on the MXU instead: a ``[BN, BM]`` one-hot (or
 edge-weighted) selection tile times a ``[BM, D]`` chunk of the source table.
 
-At ``Precision.HIGHEST`` a one-hot row times an f32 table reproduces the
-selected row exactly (each f32 splits into bf16 parts that the MXU multiplies
-by 1.0 without rounding), so ``gather_rows`` moves rows bit for bit.  A
+At ``MATMUL_PRECISION`` ("highest") a one-hot row times an f32 table
+reproduces the selected row exactly (each f32 splits into bf16 parts that
+the MXU multiplies by 1.0 without rounding), so ``gather_rows`` moves rows bit for bit.  A
 weighted tile is the padded SpMM itself: one matmul per chunk instead of K
 row gathers.
 
@@ -23,14 +23,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-HIGHEST = jax.lax.Precision.HIGHEST
+# Features, weights and kernels are f32, and so is every matmul of the HGNN
+# forward: on TPU, XLA's default precision runs an f32 dot as a single bf16
+# pass, which a float32 reference at f32 tolerance rejects.  The executor
+# traces its forward under this precision; the kernels pass it explicitly.
+MATMUL_PRECISION = "highest"
 
 
 def matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     """f32 MXU product at full precision.  The TPU default would round f32
     operands to one bf16 pass, which the float32 references would catch."""
     return jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32),
-                   precision=HIGHEST, preferred_element_type=jnp.float32)
+                   precision=MATMUL_PRECISION,
+                   preferred_element_type=jnp.float32)
 
 
 def column(x: jax.Array, j) -> jax.Array:

@@ -81,23 +81,27 @@ class _TypeTable:
         self.n_type = n_type
         self.cap = cap
         self.identity = cap == n_type
+        # a target requested twice in one batch (two slots of a serving
+        # step may ask for the same vertex) keeps one row, in first-request
+        # order, so relabeling stays a bijection
+        _, first = np.unique(targets, return_index=True)
+        self.n_targets = len(first)
         if self.identity:
             self.ids = np.arange(n_type, dtype=np.int64)
             self.truncated = 0
         else:
-            ids = np.concatenate([targets, frontier])
+            ids = np.concatenate([targets[np.sort(first)], frontier])
             self.truncated = max(0, len(ids) - cap)
             if self.truncated:
                 # never drop targets: the engine sizes chunks to t_cap and
                 # the frontier is hop-ordered, so the tail is the far rim
-                assert len(targets) <= cap, (
-                    f"targets ({len(targets)}) overflow the rung cap ({cap})")
+                assert self.n_targets <= cap, (
+                    f"targets ({self.n_targets}) overflow the rung cap ({cap})")
                 ids = ids[:cap]
             self.ids = ids
         self.n_real = len(self.ids)
         self._lut = np.full(n_type, -1, np.int64)
-        self._lut[self.ids[::-1]] = np.arange(self.n_real)[::-1]
-        # duplicate target ids map to their first occurrence
+        self._lut[self.ids] = np.arange(self.n_real)
 
     def relabel(self, ids: np.ndarray) -> np.ndarray:
         """Global -> local; dropped (truncated) ids come back as -1."""
@@ -267,7 +271,7 @@ class HGNNSampler:
     def _meta(self, rung_i: int, targets: np.ndarray,
               tables: Dict[str, _TypeTable], index_bytes: int) -> Dict:
         frontier_rows = {
-            t: int(tb.n_real - (len(targets) if t == self.target else 0))
+            t: int(tb.n_real - (tb.n_targets if t == self.target else 0))
             for t, tb in tables.items()
         }
         frontier_bytes = sum(
