@@ -433,34 +433,6 @@ def partition_traffic(part: Dict, h_own: Dict, layers: int = 1) -> Dict:
     }
 
 
-# ---------------------------------------------------------------------------
-# request-path sampled-serving traffic record
-# ---------------------------------------------------------------------------
-
-
-def sample_traffic(meta: Dict) -> Dict:
-    """SAMPLE-stage record for request-path serving.
-
-    ``meta`` is :class:`repro.serve.sampler.SampledBatch`'s host-side batch
-    metadata.  The record is fully deterministic given (graph, seed,
-    targets, fan-out) — the quantities the serving bench *gates* — and it is
-    the paper taxonomy's Subgraph Build stage realized as the per-request
-    neighbor-sampling gather: the frontier feature rows that must be
-    fetched beyond the targets themselves (``frontier_bytes``) plus the
-    relabeled index tables shipped to the device (``index_bytes``).
-    """
-    return {
-        "rung": list(meta["rung"]),
-        "rung_index": int(meta["rung_index"]),
-        "n_targets": int(meta["n_targets"]),
-        "frontier_rows": int(meta["frontier_rows"]),
-        "frontier_bytes": float(meta["frontier_bytes"]),
-        "index_bytes": float(meta["index_bytes"]),
-        "truncated_rows": int(meta["truncated_rows"]),
-        "fanout": int(meta["fanout"]),
-    }
-
-
 def residency_record(counters: Dict, row_bytes: int, layers: int = 1) -> Dict:
     """Hot-feature residency record (``repro.core.residency``).
 

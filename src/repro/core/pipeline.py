@@ -813,21 +813,32 @@ class StageGraphExecutor:
         """The L-layer loop: per-type feature tables are the carried state;
         layer 0 reads the prepared batch, hidden layers the previous
         handoff.  The partitioned flow re-exchanges the *updated* halo
-        features every layer over the graph-invariant halo maps."""
+        features every layer over the graph-invariant halo maps.
+
+        Each stage runs under a ``jax.named_scope`` of its
+        :meth:`stage_fns` name (``FP``, ``gather_halo``, ``NA``, ``SA``,
+        ``head``; ``L{i}.``-prefixed when L > 1), so the compiled ops name
+        their stage; the layer handoff is in no stage."""
         plan = self.plan
         state = out = None
         with jax.default_matmul_precision(MATMUL_PRECISION):
             for l, lp in enumerate(plan.layers):
+                pre = f"L{l + 1}." if plan.n_layers > 1 else ""
                 p_l = self._layer_params(params, l)
-                h = (self.fp(params, batch) if l == 0
-                     else self._fp_hidden(lp, p_l, state))
+                with jax.named_scope(pre + "FP"):
+                    h = (self.fp(params, batch) if l == 0
+                         else self._fp_hidden(lp, p_l, state))
                 if plan.partition is not None:
-                    h = self.gather_halo(batch, h)
-                z = self.na(p_l, batch, h)
-                out = self.sa(p_l, batch, z)
+                    with jax.named_scope(pre + "gather_halo"):
+                        h = self.gather_halo(batch, h)
+                with jax.named_scope(pre + "NA"):
+                    z = self.na(p_l, batch, h)
+                with jax.named_scope(pre + "SA"):
+                    out = self.sa(p_l, batch, z)
                 if l + 1 < plan.n_layers:
                     state = self._handoff(lp, batch, h, out)
-            return self.head(params, out, batch)
+            with jax.named_scope("head"):
+                return self.head(params, out, batch)
 
     # ------------------------------------------------------------------
     # the async stage-graph schedule (plan.schedule)
@@ -1101,20 +1112,19 @@ class StageGraphExecutor:
         (the fully-jitted forward may fuse across stage boundaries, so the
         per-stage attribution is the meaningful decomposition).
 
-        ``sample_meta`` (request-path serving): the sampler's host-side
-        batch metadata; adds the SAMPLE stage — the paper taxonomy's
-        Subgraph Build, realized as the neighbor-sampling gather — as the
-        first record (``characterize.sample_traffic``), with its traffic
-        kept out of the compiled-stage ``total``."""
+        ``sample_meta`` (request-path serving): the sampler's
+        ``SampledBatch.meta``; it becomes the SAMPLE stage — the paper
+        taxonomy's Subgraph Build, realized as the neighbor-sampling gather
+        — as the first record, with its traffic kept out of the
+        compiled-stage ``total``."""
         from repro.core.characterize import (analyze_hlo_text,
                                              partition_traffic,
-                                             residency_record, roofline,
-                                             sample_traffic)
+                                             residency_record, roofline)
 
         fns = self.stage_fns(params, batch)
         recs: Dict[str, Dict] = {}
         if sample_meta is not None:
-            recs["SAMPLE"] = sample_traffic(sample_meta)
+            recs["SAMPLE"] = sample_meta
         for name, (fn, args) in fns.items():
             rep = analyze_hlo_text(fn.lower(*args).compile().as_text())
             recs[name] = {

@@ -27,6 +27,13 @@ from repro.serve import resilience
 from repro.serve.resilience import (
     FAILED, OK, PARTIAL, AdmissionController, DegradationController,
     ResilienceConfig, RetryPolicy, StepFailure, finalize_request)
+from repro.serve.spans import span
+
+# what the sampler's spans and counter put in SampledBatch.meta
+SAMPLE_PARTS = ("expand_s", "gather_s", "upload_s", "upload_bytes")
+# the keys every step_log record gains from its spans and counters
+STEP_PARTS = ("refill_s", "sample_s", "forward_s", "scatter_s",
+              *SAMPLE_PARTS, "recompiled")
 
 
 class HGNNInferEngine:
@@ -49,7 +56,8 @@ class HGNNInferEngine:
 
     def infer(self) -> jax.Array:
         """One full forward over the prepared batch -> logits."""
-        return self.fn(self.params, self.batch)
+        with span("hgnn.infer"):
+            return self.fn(self.params, self.batch)
 
     def characterize(self, n_chips: int = 1) -> Dict[str, Dict]:
         """Per-stage (FP/NA/SA/head) FLOPs / HBM bytes / roofline records
@@ -82,6 +90,17 @@ class HGNNRequest:
     _inv: Optional[np.ndarray] = None        # original row -> _serve_ids row
     _buf: Optional[np.ndarray] = None        # [len(_serve_ids), C] working
     _deadline: Optional[float] = None        # absolute perf_counter deadline
+    # timeline on the perf_counter clock, stamped by the engine: ``seq`` is
+    # the engine's admission order (the id its step records list),
+    # ``admitted_at`` when ``serve`` took the request, ``started_at`` the
+    # start of the first step that served it, ``finished_at`` when it turned
+    # terminal; ``steps`` the step indices (within the ``serve`` call) that
+    # served it
+    seq: Optional[int] = None
+    admitted_at: Optional[float] = None
+    started_at: Optional[float] = None
+    finished_at: Optional[float] = None
+    steps: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def finished(self) -> bool:
@@ -223,6 +242,7 @@ class HGNNServeEngine:
                     self.plan.partition, static_shapes=True))
         self._warm_compiles: Optional[int] = None
         self.step_log: List[Dict] = []
+        self._seq = 0  # next request's admission number
         self.last_sb = None
         # residency: live per-type hot-row caches over the sampled frontier
         # (repro.core.residency.HotRowCache).  Keyed by GLOBAL vertex ids and
@@ -353,6 +373,11 @@ class HGNNServeEngine:
         Never raises for admissible traffic: bad requests are REJECTED at
         admission, deadline-expired ones complete PARTIAL, and persistent
         step errors FAIL only the requests in the affected slots.
+
+        Each step runs under the ``hgnn.serve.*`` spans
+        (``repro.serve.spans``); its ``step_log`` record carries their
+        seconds, the sampler's phases and uploaded bytes, the forward's
+        recompiles, and the ``seq`` of every request it served.
         """
         import collections
         import time
@@ -362,144 +387,191 @@ class HGNNServeEngine:
         now = time.perf_counter()
         q: collections.deque = collections.deque()
         for r in requests:
+            r.seq, r.admitted_at = self._seq, now
+            self._seq += 1
             if adm.admit(r, len(q), now):
                 q.append(r)
+            else:  # rejected or degenerate: terminal at admission
+                r.finished_at = now
         active: List[Optional[HGNNRequest]] = [None] * self.slots
         self.step_log = []
         step = 0
         while q or any(r is not None for r in active):
-            now = time.perf_counter()
-            # deadline expiry: active slots and queued requests complete
-            # PARTIAL (rows served so far) without blocking the loop
-            active, n_exp = resilience.expire_requests(
-                active, now, self.n_classes)
-            self._deadline_expired += n_exp
-            if q:
-                live: collections.deque = collections.deque()
-                for r in q:
-                    if r._deadline is not None and now >= r._deadline:
-                        finalize_request(r, PARTIAL, self.n_classes,
-                                         error="deadline expired")
-                        self._deadline_expired += 1
-                    else:
-                        live.append(r)
-                q = live
-            # refill: degenerate requests completed at admission, so every
-            # queued request is servable and takes exactly one free slot
-            for s in range(self.slots):
-                if active[s] is None and q:
-                    active[s] = q.popleft()
-                    active[s].status = "ACTIVE"
-            # degradation: per-slot chunk + rung clamp (warmed rungs only)
-            level_used = deg.level
-            chunk = deg.chunk()
-            rung_limit = deg.rung_limit()
-            t_budget = self.sampler.ladder[rung_limit][0]
-            chunks = []  # (request, start_row_in_request, ids)
-            n_union = 0
-            for r in active:
-                if r is None:
+            rec: Dict = {"step": step, **dict.fromkeys(STEP_PARTS, 0)}
+            with span("hgnn.serve.step", rec, "step_s", step=step) as sp:
+                with span("hgnn.serve.refill", rec, "refill_s"):
+                    q, active, chunks, level_used, rung_limit = self._refill(
+                        q, active)
+                    if chunks:
+                        self._maybe_failover(step)
+                        ids = np.concatenate([c[2] for c in chunks])
+                if not chunks:  # everything expired this pass
                     continue
-                if n_union >= t_budget:
-                    break  # degraded union budget: remaining slots wait
-                take = min(chunk, t_budget - n_union,
-                           len(r._serve_ids) - r._done)
-                ids = r._serve_ids[r._done: r._done + take]
-                chunks.append((r, r._done, np.asarray(ids, np.int64)))
-                n_union += take
-            if not chunks:  # everything expired this pass
-                continue
-            self._maybe_failover(step)
-            ids = np.concatenate([c[2] for c in chunks])
-            t0 = time.perf_counter()
-            inj = self.injector
-            # prefetch hit: the speculative batch stands in for the sampler
-            # call but still runs under the SAME retry policy and fault
-            # hook, so injected sampler faults (and their counters) fire
-            # identically whether the batch was prefetched or sampled sync
-            sb_pre = (self.prefetch.take(ids, rung_limit)
-                      if self.prefetch is not None else None)
-            sample_call = ((lambda: sb_pre) if sb_pre is not None else
-                           (lambda: self.sampler.sample(
-                               ids, max_rung=rung_limit)))
-            try:
-                sb = retry.run(
-                    "sampler", sample_call,
-                    hook=(lambda a: inj.check("sampler", step, a))
-                    if inj else None)
-                if self.prefetch is not None:
-                    nxt = self._predict_next(active, q, chunks)
-                    if nxt is not None:
-                        self.prefetch.submit(*nxt)
-                out = retry.run(
-                    "forward",
-                    lambda: np.asarray(
-                        self.fn(self.params, self._forward_batch(sb.batch))),
-                    hook=(lambda a: inj.check("forward", step, a))
-                    if inj else None)
-            except StepFailure as e:
-                wall = time.perf_counter() - t0
-                inj_lat = inj.latency_s(step) if inj else 0.0
-                wall_obs = wall + inj_lat
+                rec["seqs"] = [r.seq for r, _start, _cids in chunks]
                 for r, _start, _cids in chunks:
-                    finalize_request(r, FAILED, self.n_classes,
-                                     error=str(e))
-                for s in range(self.slots):
-                    if active[s] is not None and active[s].status == FAILED:
-                        active[s] = None
-                deg.observe(inj_lat if self.res.slo_signal == "injected"
-                            else wall_obs)
-                self.step_log.append({
-                    "active_slots": len(chunks), "queue_len": len(q),
-                    "n_targets": int(len(ids)), "rung_index": -1,
-                    "frontier_bytes": 0.0, "truncated_rows": 0,
-                    "wall_s": wall, "wall_observed_s": wall_obs,
-                    "degrade_level": level_used, "failed": True,
-                    "error": str(e),
+                    if r.started_at is None:
+                        r.started_at = sp.t0
+                    r.steps.append(step)
+                t0 = time.perf_counter()
+                inj = self.injector
+                try:
+                    with span("hgnn.serve.sample", rec, "sample_s"):
+                        sb = self._sample(ids, rung_limit, step, active, q,
+                                          chunks)
+                    for k in SAMPLE_PARTS:
+                        rec[k] = sb.meta[k]
+                    with span("hgnn.forward", rec, "forward_s"):
+                        n_compiled = self.fn._cache_size()
+                        out = retry.run(
+                            "forward",
+                            lambda: np.asarray(self.fn(
+                                self.params, self._forward_batch(sb.batch))),
+                            hook=(lambda a: inj.check("forward", step, a))
+                            if inj else None)
+                        rec["recompiled"] = (self.fn._cache_size()
+                                             - n_compiled)
+                except StepFailure as e:
+                    wall = time.perf_counter() - t0
+                    inj_lat = inj.latency_s(step) if inj else 0.0
+                    wall_obs = wall + inj_lat
+                    with span("hgnn.serve.scatter", rec, "scatter_s"):
+                        for r, _start, _cids in chunks:
+                            finalize_request(r, FAILED, self.n_classes,
+                                             error=str(e))
+                        for s in range(self.slots):
+                            if (active[s] is not None
+                                    and active[s].status == FAILED):
+                                active[s] = None
+                        deg.observe(inj_lat
+                                    if self.res.slo_signal == "injected"
+                                    else wall_obs)
+                    rec.update({
+                        "active_slots": len(chunks), "queue_len": len(q),
+                        "n_targets": int(len(ids)), "rung_index": -1,
+                        "frontier_bytes": 0.0, "truncated_rows": 0,
+                        "wall_s": wall, "wall_observed_s": wall_obs,
+                        "degrade_level": level_used, "failed": True,
+                        "error": str(e),
+                    })
+                    self.step_log.append(rec)
+                    step += 1
+                    continue
+                with span("hgnn.serve.scatter", rec, "scatter_s"):
+                    rows = out[sb.target_rows]
+                    wall = time.perf_counter() - t0
+                    if self.caches is not None:  # host bookkeeping
+                        self._cache_step(ids, sb)
+                    inj_lat = inj.latency_s(step) if inj else 0.0
+                    wall_obs = wall + inj_lat
+                    off = 0
+                    for r, start, cids in chunks:
+                        n = len(cids)
+                        if r._buf is None:
+                            r._buf = np.zeros(
+                                (len(r._serve_ids), rows.shape[1]),
+                                rows.dtype)
+                        r._buf[start: start + n] = rows[off: off + n]
+                        r._done = start + n
+                        off += n
+                    for s in range(self.slots):
+                        r = active[s]
+                        if r is not None and r._done >= len(r._serve_ids):
+                            finalize_request(r, OK, self.n_classes)
+                            active[s] = None
+                    deg.observe(inj_lat if self.res.slo_signal == "injected"
+                                else wall_obs)
+                rec.update({
+                    "active_slots": len(chunks),
+                    "queue_len": len(q),
+                    "n_targets": int(sb.n_targets),
+                    "rung_index": int(sb.rung_index),
+                    "frontier_bytes": float(sb.meta["frontier_bytes"]),
+                    "truncated_rows": int(sb.meta["truncated_rows"]),
+                    "wall_s": wall,
+                    "wall_observed_s": wall_obs,
+                    "degrade_level": level_used,
                 })
+                self.step_log.append(rec)
+                self.last_sb = sb
                 step += 1
-                continue
-            rows = out[sb.target_rows]
-            wall = time.perf_counter() - t0
-            if self.caches is not None:  # host bookkeeping, untimed
-                self._cache_step(ids, sb)
-            inj_lat = inj.latency_s(step) if inj else 0.0
-            wall_obs = wall + inj_lat
-            off = 0
-            for r, start, cids in chunks:
-                n = len(cids)
-                if r._buf is None:
-                    r._buf = np.zeros((len(r._serve_ids), rows.shape[1]),
-                                      rows.dtype)
-                r._buf[start: start + n] = rows[off: off + n]
-                r._done = start + n
-                off += n
-            for s in range(self.slots):
-                r = active[s]
-                if r is not None and r._done >= len(r._serve_ids):
-                    finalize_request(r, OK, self.n_classes)
-                    active[s] = None
-            deg.observe(inj_lat if self.res.slo_signal == "injected"
-                        else wall_obs)
-            self.step_log.append({
-                "active_slots": len(chunks),
-                "queue_len": len(q),
-                "n_targets": int(sb.n_targets),
-                "rung_index": int(sb.rung_index),
-                "frontier_bytes": float(sb.meta["frontier_bytes"]),
-                "truncated_rows": int(sb.meta["truncated_rows"]),
-                "wall_s": wall,
-                "wall_observed_s": wall_obs,
-                "degrade_level": level_used,
-            })
-            self.last_sb = sb
-            step += 1
         if self.prefetch is not None:
             self.prefetch.drain()
         for r in requests:
             self._status_counts[r.status] = (
                 self._status_counts.get(r.status, 0) + 1)
         return requests
+
+    def _refill(self, q, active):
+        """One step's expiry, refill and chunking.  Returns the live queue
+        and slots, the chunks ``(request, start_row, ids)`` to serve, the
+        degradation level used and the rung limit."""
+        import collections
+        import time
+
+        deg = self.degrade
+        now = time.perf_counter()
+        # deadline expiry: active slots and queued requests complete
+        # PARTIAL (rows served so far) without blocking the loop
+        active, n_exp = resilience.expire_requests(
+            active, now, self.n_classes)
+        self._deadline_expired += n_exp
+        if q:
+            live: collections.deque = collections.deque()
+            for r in q:
+                if r._deadline is not None and now >= r._deadline:
+                    finalize_request(r, PARTIAL, self.n_classes,
+                                     error="deadline expired")
+                    self._deadline_expired += 1
+                else:
+                    live.append(r)
+            q = live
+        # refill: degenerate requests completed at admission, so every
+        # queued request is servable and takes exactly one free slot
+        for s in range(self.slots):
+            if active[s] is None and q:
+                active[s] = q.popleft()
+                active[s].status = "ACTIVE"
+        # degradation: per-slot chunk + rung clamp (warmed rungs only)
+        level_used = deg.level
+        chunk = deg.chunk()
+        rung_limit = deg.rung_limit()
+        t_budget = self.sampler.ladder[rung_limit][0]
+        chunks = []  # (request, start_row_in_request, ids)
+        n_union = 0
+        for r in active:
+            if r is None:
+                continue
+            if n_union >= t_budget:
+                break  # degraded union budget: remaining slots wait
+            take = min(chunk, t_budget - n_union,
+                       len(r._serve_ids) - r._done)
+            ids = r._serve_ids[r._done: r._done + take]
+            chunks.append((r, r._done, np.asarray(ids, np.int64)))
+            n_union += take
+        return q, active, chunks, level_used, rung_limit
+
+    def _sample(self, ids, rung_limit, step, active, q, chunks):
+        """The step's sampled batch, under the retry policy and the fault
+        hook; then the next step's speculative sample, when prefetching."""
+        inj = self.injector
+        # prefetch hit: the speculative batch stands in for the sampler
+        # call but still runs under the SAME retry policy and fault
+        # hook, so injected sampler faults (and their counters) fire
+        # identically whether the batch was prefetched or sampled sync
+        sb_pre = (self.prefetch.take(ids, rung_limit)
+                  if self.prefetch is not None else None)
+        sample_call = ((lambda: sb_pre) if sb_pre is not None else
+                       (lambda: self.sampler.sample(
+                           ids, max_rung=rung_limit)))
+        sb = self.retry.run(
+            "sampler", sample_call,
+            hook=(lambda a: inj.check("sampler", step, a))
+            if inj else None)
+        if self.prefetch is not None:
+            nxt = self._predict_next(active, q, chunks)
+            if nxt is not None:
+                self.prefetch.submit(*nxt)
+        return sb
 
     def stats(self) -> Dict:
         """Deterministic serving counters (walls reported, never gated).

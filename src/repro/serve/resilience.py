@@ -293,8 +293,10 @@ def finalize_request(r, status: str, n_classes: int,
     ``OK``: every unique id served — ``logits`` has one row per original
     target (duplicates fanned out).  ``PARTIAL``/``FAILED``: only rows
     whose unique id was served survive, compacted in request order, with
-    ``served`` naming the target ids those rows answer.
+    ``served`` naming the target ids those rows answer.  Stamps
+    ``finished_at`` (``perf_counter``).
     """
+    r.finished_at = time.perf_counter()
     if r._serve_ids is None:  # rejected/degenerate: already finalized
         r.status = status
         if error is not None:

@@ -35,14 +35,17 @@ degree-capped with the model's RNG).  Overflowing a rung truncates the
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+import functools
+from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import metapath as mp
 from repro.core.hgraph import HeteroGraph
 from repro.core.plan import StagePlan
+from repro.serve.spans import span
 
 
 @dataclasses.dataclass
@@ -55,7 +58,9 @@ class SampledBatch:
     rung: Tuple[int, int]
     rung_index: int
     local: Dict[str, np.ndarray]  # type -> [n_real] local->global id map
-    meta: Dict  # deterministic traffic record (characterize.sample_traffic)
+    # the traffic record (rung, targets, frontier rows and bytes, truncation)
+    # and the phases' host seconds and uploaded bytes
+    meta: Dict
 
     @property
     def n_targets(self) -> int:
@@ -233,19 +238,46 @@ class HGNNSampler:
     # ------------------------------------------------------------------
     def sample(self, targets: np.ndarray, rung: Optional[int] = None,
                max_rung: Optional[int] = None) -> SampledBatch:
+        """Expand the targets' frontier and choose a rung, then gather the
+        local tables, each array put on the device as soon as it is built
+        (:func:`_upload`) — each phase under its span
+        (``repro.serve.spans``), timed into ``meta``.  ``gather_s`` leaves
+        out the uploads nested in it."""
         targets = np.asarray(targets, np.int64).reshape(-1)
         if len(targets) and (targets.min() < 0
                              or targets.max() >= self.n_target_type):
             raise ValueError(f"target ids out of range for type "
                              f"{self.target!r} ({self.n_target_type} nodes)")
         kind = self.plan.na.kind
-        if kind == "gat":
-            return self._sample_gat(targets, rung, max_rung)
-        if kind == "mean":
-            return self._sample_mean(targets, rung, max_rung)
-        if kind == "instance":
-            return self._sample_instance(targets, rung, max_rung)
-        return self._sample_gcn(targets, rung, max_rung)
+        rec: Dict = {"upload_s": 0.0, "upload_bytes": 0}
+        up = functools.partial(_upload, rec=rec)
+        with span("hgnn.sample"):
+            with span("hgnn.sample.expand", rec, "expand_s"):
+                fr = getattr(self, f"_expand_{kind}")(targets)
+                tgts = {t: targets if t == self.target
+                        else np.zeros(0, np.int64) for t in fr}
+                need = {t: len(tgts[t]) + len(fr[t]) for t in fr}
+                rung_i = (self.pick_rung(len(targets), need, max_rung)
+                          if rung is None else rung)
+            with span("hgnn.sample.gather", rec, "gather_s"):
+                f_cap = self.spec.ladder[rung_i][1]
+                tables = {t: _TypeTable(self.hg.node_counts[t],
+                                        self._clamp(f_cap, t), tgts[t], fr[t])
+                          for t in fr}
+                batch = getattr(self, f"_gather_{kind}")(tables, up)
+                tt = tables[self.target]
+                target_rows = (targets.copy() if tt.identity
+                               else tt.relabel(targets))
+            rec["gather_s"] -= rec["upload_s"]
+        return SampledBatch(
+            batch=batch,
+            target_ids=targets,
+            target_rows=target_rows,
+            rung=tuple(self.spec.ladder[rung_i]),
+            rung_index=rung_i,
+            local={t: tb.ids for t, tb in tables.items()},
+            meta={**self._meta(rung_i, targets, tables), **rec},
+        )
 
     def dummy_batch(self, rung: int) -> SampledBatch:
         """An all-pad batch at the rung's exact shapes — warmup compiles the
@@ -269,7 +301,7 @@ class HGNNSampler:
         return np.asarray(out, np.int64)
 
     def _meta(self, rung_i: int, targets: np.ndarray,
-              tables: Dict[str, _TypeTable], index_bytes: int) -> Dict:
+              tables: Dict[str, _TypeTable]) -> Dict:
         frontier_rows = {
             t: int(tb.n_real - (tb.n_targets if t == self.target else 0))
             for t, tb in tables.items()
@@ -283,36 +315,20 @@ class HGNNSampler:
             "n_targets": int(len(targets)),
             "frontier_rows": int(sum(frontier_rows.values())),
             "frontier_bytes": int(frontier_bytes),
-            "index_bytes": int(index_bytes),
             "truncated_rows": int(sum(tb.truncated for tb in tables.values())),
             "fanout": int(self.spec.fanout),
         }
 
-    def _finish(self, batch: Dict, targets: np.ndarray, rung_i: int,
-                tables: Dict[str, _TypeTable], index_bytes: int,
-                ) -> SampledBatch:
-        tt = tables[self.target]
-        target_rows = (targets.copy() if tt.identity
-                       else tt.relabel(targets))
-        return SampledBatch(
-            batch=batch,
-            target_ids=targets,
-            target_rows=target_rows,
-            rung=tuple(self.spec.ladder[rung_i]),
-            rung_index=rung_i,
-            local={t: tb.ids for t, tb in tables.items()},
-            meta=self._meta(rung_i, targets, tables, index_bytes),
-        )
-
-    def _row_mask(self, table: _TypeTable) -> jnp.ndarray:
+    @staticmethod
+    def _row_mask(table: _TypeTable) -> np.ndarray:
         m = np.zeros(table.cap, np.float32)
         m[: table.n_real] = 1.0
-        return jnp.asarray(m)
+        return m
 
     # ------------------------------------------------------------------
     # HAN — stacked / bucketed metapath tables (target->target graphs)
     # ------------------------------------------------------------------
-    def _expand_gat(self, targets: np.ndarray) -> List[np.ndarray]:
+    def _expand_gat(self, targets: np.ndarray) -> Dict[str, np.ndarray]:
         """Per-hop frontier over the union of the metapath graphs; hop
         count = n_layers (each layer re-aggregates the same graphs)."""
         k = self.k_eff
@@ -334,39 +350,23 @@ class HGNNSampler:
             hop_sets.append(new)
             known.update(new.tolist())
             cur = new
-        return hop_sets
+        return {self.target: self._frontier_order(hop_sets, targets)}
 
-    def _sample_gat(self, targets: np.ndarray, rung: Optional[int],
-                    max_rung: Optional[int] = None) -> SampledBatch:
+    def _gather_gat(self, tables: Dict[str, _TypeTable], up: Callable) -> Dict:
         cfg, plan = self.cfg, self.plan
         k = self.k_eff
-        hop_sets = self._expand_gat(targets)
-        frontier = self._frontier_order(hop_sets, targets)
-        need = {self.target: len(targets) + len(frontier)}
-        rung_i = (self.pick_rung(len(targets), need, max_rung)
-                  if rung is None else rung)
-        f_cap = self._clamp(self.spec.ladder[rung_i][1], self.target)
-        table = _TypeTable(self.n_target_type, f_cap, targets, frontier)
-        tables = {self.target: table}
-
-        feats = table.rows(self.hg.features[self.target])
+        table = tables[self.target]
         batch: Dict = {
-            "feats": {self.target: jnp.asarray(feats)},
+            "feats": {self.target: up(
+                table.rows(self.hg.features[self.target]))},
             "feat_dims": {self.target: self.feat_dims[self.target]},
             "n_nodes": table.cap,
-            "row_mask": self._row_mask(table),
+            "row_mask": up(self._row_mask(table)),
         }
-        index_bytes = 0
         if plan.na.layout == "bucketed":
-            bks = []
-            for b in self.full_buckets:
-                bks.append(self._local_buckets(b, table, k))
-                index_bytes += sum(r.nbytes + n.nbytes + m.nbytes
-                                   for r, n, m in bks[-1])
-            batch["buckets"] = [
-                [(jnp.asarray(r), jnp.asarray(n), jnp.asarray(m))
-                 for r, n, m in bk] for bk in bks
-            ]
+            batch["buckets"] = [[tuple(map(up, rnm))
+                                 for rnm in self._local_buckets(b, table, k)]
+                                for b in self.full_buckets]
         else:  # stacked
             if table.identity and k == cfg.max_degree:
                 nbr, mask = mp.stack_padded(self.subs)
@@ -378,10 +378,9 @@ class HGNNSampler:
                     mp.PaddedSubgraph(n, m, list(p))
                     for (n, m), p in zip(locs, plan.metapaths)
                 ])
-            index_bytes += nbr.nbytes + mask.nbytes
-            batch["nbr"] = jnp.asarray(nbr)
-            batch["mask"] = jnp.asarray(mask)
-        return self._finish(batch, targets, rung_i, tables, index_bytes)
+            batch["nbr"] = up(nbr)
+            batch["mask"] = up(mask)
+        return batch
 
     def _local_padded(self, nbr: np.ndarray, mask: np.ndarray,
                       dst: _TypeTable, src: _TypeTable,
@@ -444,9 +443,7 @@ class HGNNSampler:
     # ------------------------------------------------------------------
     # RGCN — per-relation padded (or bucketed) tables, typed k-hop ball
     # ------------------------------------------------------------------
-    def _sample_mean(self, targets: np.ndarray, rung: Optional[int],
-                     max_rung: Optional[int] = None) -> SampledBatch:
-        cfg, plan = self.cfg, self.plan
+    def _expand_mean(self, targets: np.ndarray) -> Dict[str, np.ndarray]:
         k = self.k_eff
         # typed frontier expansion: per hop, every relation (s, r, d) pulls
         # the in-neighbors (type s) of the currently-needed rows of type d
@@ -457,7 +454,7 @@ class HGNNSampler:
             t: np.zeros(0, np.int64) for t in self.hg.node_counts}
         cur[self.target] = np.unique(targets)
         known[self.target].update(cur[self.target].tolist())
-        for _ in range(plan.n_layers):
+        for _ in range(self.plan.n_layers):
             nxt: Dict[str, List[np.ndarray]] = {
                 t: [] for t in self.hg.node_counts}
             for key in self.rel_keys:
@@ -481,51 +478,37 @@ class HGNNSampler:
             cur = new_cur
             if not any(len(v) for v in cur.values()):
                 break
+        return {t: self._frontier_order(
+                    per_type_hops[t],
+                    targets if t == self.target else np.zeros(0, np.int64))
+                for t in self.hg.node_counts}
 
-        tables: Dict[str, _TypeTable] = {}
-        need: Dict[str, int] = {}
-        for t in self.hg.node_counts:
-            tgt = targets if t == self.target else np.zeros(0, np.int64)
-            frontier = self._frontier_order(per_type_hops[t], tgt)
-            need[t] = len(tgt) + len(frontier)
-        rung_i = (self.pick_rung(len(targets), need, max_rung)
-                  if rung is None else rung)
-        f_cap = self.spec.ladder[rung_i][1]
-        for t in self.hg.node_counts:
-            tgt = targets if t == self.target else np.zeros(0, np.int64)
-            frontier = self._frontier_order(per_type_hops[t], tgt)
-            tables[t] = _TypeTable(self.hg.node_counts[t],
-                                   self._clamp(f_cap, t), tgt, frontier)
-
+    def _gather_mean(self, tables: Dict[str, _TypeTable], up: Callable,
+                     ) -> Dict:
+        cfg, plan = self.cfg, self.plan
+        k = self.k_eff
         batch: Dict = {
-            "feats": {t: jnp.asarray(tables[t].rows(self.hg.features[t]))
+            "feats": {t: up(tables[t].rows(self.hg.features[t]))
                       for t in self.hg.features},
             "counts": {t: tables[t].cap for t in self.hg.node_counts},
             "feat_dims": dict(self.feat_dims),
             "rels": {},
         }
-        index_bytes = 0
         for key in self.rel_keys:
             s, _, d = key
             if plan.na.layout == "bucketed":
-                bk = self._local_buckets_rel(key, tables[d], tables[s], k)
-                index_bytes += sum(r.nbytes + n.nbytes + m.nbytes
-                                   for r, n, m in bk)
                 batch["rels"][key] = [
-                    (jnp.asarray(r), jnp.asarray(n), jnp.asarray(m))
-                    for r, n, m in bk
-                ]
+                    tuple(map(up, rnm)) for rnm in self._local_buckets_rel(
+                        key, tables[d], tables[s], k)]
             else:
                 nbr, mask = self.rel_tables[key]
                 if (tables[d].identity and tables[s].identity
                         and k == cfg.max_degree):
-                    loc_n, loc_m = nbr, mask
+                    batch["rels"][key] = (up(nbr), up(mask))
                 else:
-                    loc_n, loc_m = self._local_padded(
-                        nbr[:, :k], mask[:, :k], tables[d], tables[s])
-                index_bytes += loc_n.nbytes + loc_m.nbytes
-                batch["rels"][key] = (jnp.asarray(loc_n), jnp.asarray(loc_m))
-        return self._finish(batch, targets, rung_i, tables, index_bytes)
+                    batch["rels"][key] = tuple(map(up, self._local_padded(
+                        nbr[:, :k], mask[:, :k], tables[d], tables[s])))
+        return batch
 
     def _local_buckets_rel(self, key, dst: _TypeTable, src: _TypeTable,
                            k: int) -> List[Tuple[np.ndarray, np.ndarray,
@@ -559,9 +542,8 @@ class HGNNSampler:
     # ------------------------------------------------------------------
     # MAGNN — instance tables; frontier = instance node sets
     # ------------------------------------------------------------------
-    def _sample_instance(self, targets: np.ndarray, rung: Optional[int],
-                         max_rung: Optional[int] = None) -> SampledBatch:
-        plan, cfg = self.plan, self.cfg
+    def _expand_instance(self, targets: np.ndarray) -> Dict[str, np.ndarray]:
+        plan = self.plan
         i_cap = self.k_eff  # instances per target (the MAGNN fan-out knob)
         # target-type rows that need REAL instance rows: the requested
         # targets plus, per extra layer, the target-type nodes appearing in
@@ -603,9 +585,6 @@ class HGNNSampler:
             for j, ty in enumerate(p):
                 per_type[ty].append(
                     np.unique(nodes[:, :, j][msk]).astype(np.int64))
-
-        tables: Dict[str, _TypeTable] = {}
-        need: Dict[str, int] = {}
         types_used = {ty for p in plan.metapaths for ty in p} | {self.target}
         fr: Dict[str, np.ndarray] = {}
         for t in sorted(types_used):
@@ -614,24 +593,20 @@ class HGNNSampler:
                                        if per_type[t] else []), np.int64)]
                     if per_type[t] else [])
             fr[t] = self._frontier_order(hops, tgt)
-            need[t] = len(tgt) + len(fr[t])
-        rung_i = (self.pick_rung(len(targets), need, max_rung)
-                  if rung is None else rung)
-        f_cap = self.spec.ladder[rung_i][1]
-        for t in sorted(types_used):
-            tgt = targets if t == self.target else np.zeros(0, np.int64)
-            tables[t] = _TypeTable(self.hg.node_counts[t],
-                                   self._clamp(f_cap, t), tgt, fr[t])
+        return fr
 
+    def _gather_instance(self, tables: Dict[str, _TypeTable], up: Callable,
+                         ) -> Dict:
+        plan, cfg = self.plan, self.cfg
+        i_cap = self.k_eff
         tt = tables[self.target]
         batch: Dict = {
-            "feats": {t: jnp.asarray(tables[t].rows(self.hg.features[t]))
-                      for t in sorted(types_used)},
-            "feat_dims": {t: self.feat_dims[t] for t in sorted(types_used)},
+            "feats": {t: up(tables[t].rows(self.hg.features[t]))
+                      for t in tables},
+            "feat_dims": {t: self.feat_dims[t] for t in tables},
             "n_nodes": tt.cap,
-            "row_mask": self._row_mask(tt),
+            "row_mask": up(self._row_mask(tt)),
         }
-        index_bytes = 0
         instances = []
         for ib, p in zip(self.insts, plan.metapaths):
             if tt.identity and i_cap == cfg.max_instances and all(
@@ -650,23 +625,20 @@ class HGNNSampler:
                     nodes[: tt.n_real, :, j] = np.where(loc < 0, 0, loc)
                 mask[: tt.n_real] = src_mask
                 nodes[mask == 0] = 0
-            index_bytes += nodes.nbytes + mask.nbytes
-            instances.append((jnp.asarray(nodes), jnp.asarray(mask)))
+            instances.append((up(nodes), up(mask)))
         batch["instances"] = instances
-        return self._finish(batch, targets, rung_i, tables, index_bytes)
+        return batch
 
     # ------------------------------------------------------------------
     # GCN — homogeneous edge list, 2 aggregation hops per layer
     # ------------------------------------------------------------------
-    def _sample_gcn(self, targets: np.ndarray, rung: Optional[int],
-                    max_rung: Optional[int] = None) -> SampledBatch:
-        plan = self.plan
+    def _expand_gcn(self, targets: np.ndarray) -> Dict[str, np.ndarray]:
         k = self.k_eff
         indptr, indices = self.csr.indptr, self.csr.indices
         cur = np.unique(targets)
         known = set(cur.tolist())
         hop_sets: List[np.ndarray] = []
-        for _ in range(2 * plan.n_layers):  # 2 aggregations per layer
+        for _ in range(2 * self.plan.n_layers):  # 2 aggregations per layer
             nxt: List[np.ndarray] = []
             for g in cur.tolist():
                 nbrs = indices[indptr[g]: indptr[g] + min(
@@ -681,13 +653,12 @@ class HGNNSampler:
             hop_sets.append(new)
             known.update(new.tolist())
             cur = new
-        frontier = self._frontier_order(hop_sets, targets)
-        need = {self.target: len(targets) + len(frontier)}
-        rung_i = (self.pick_rung(len(targets), need, max_rung)
-                  if rung is None else rung)
-        f_cap = self._clamp(self.spec.ladder[rung_i][1], self.target)
-        table = _TypeTable(self.n_target_type, f_cap, targets, frontier)
+        return {self.target: self._frontier_order(hop_sets, targets)}
 
+    def _gather_gcn(self, tables: Dict[str, _TypeTable], up: Callable) -> Dict:
+        k = self.k_eff
+        indptr, indices = self.csr.indptr, self.csr.indices
+        table = tables[self.target]
         if table.identity and k == self.max_deg:
             seg, idx = (np.repeat(np.arange(table.cap, dtype=np.int32),
                                   np.diff(indptr)),
@@ -706,12 +677,22 @@ class HGNNSampler:
                 seg[e: e + len(loc)] = u_loc
                 idx[e: e + len(loc)] = loc
                 e += len(loc)
-        batch: Dict = {
-            "x": jnp.asarray(table.rows(self.hg.features[self.target])),
-            "seg": jnp.asarray(seg),
-            "idx": jnp.asarray(idx),
+        return {
+            "x": up(table.rows(self.hg.features[self.target])),
+            "seg": up(seg),
+            "idx": up(idx),
             "n_nodes": table.cap,
             "feat_dim": self.feat_dims[self.target],
         }
-        return self._finish(batch, targets, rung_i, {self.target: table},
-                            int(seg.nbytes + idx.nbytes))
+
+
+def _upload(x: np.ndarray, rec: Dict) -> jax.Array:
+    """Put one array of a batch on the device under the
+    ``hgnn.sample.upload`` span, adding its seconds and its bytes (padded,
+    as on the device) to ``rec``.  Each array goes up as soon as it is
+    built: one transfer of the whole batch at the end measured slower on
+    a TPU v5e for R-GCN's wide tables (``PERF.md``)."""
+    with span("hgnn.sample.upload", rec, "upload_s"):
+        out = jnp.asarray(x)
+    rec["upload_bytes"] += out.nbytes
+    return out
