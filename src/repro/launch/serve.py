@@ -221,7 +221,8 @@ def run_hgnn_serve(args, cfg: HGNNConfig, hg, built: BuiltHGNNInfer) -> None:
         print(f"  SAMPLE: rung={sm['rung']} n_targets={sm['n_targets']} "
               f"frontier_rows={sm['frontier_rows']} "
               f"frontier_bytes={sm['frontier_bytes']:.3g} "
-              f"upload_bytes={sm['upload_bytes']:.3g}")
+              f"upload_bytes={sm['upload_bytes']:.3g} "
+              f"resident_gather_bytes={sm['resident_gather_bytes']:.3g}")
         for stage, rec in recs["stages"].items():
             if stage == "SAMPLE":
                 continue

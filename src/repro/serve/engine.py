@@ -29,8 +29,9 @@ from repro.serve.resilience import (
     ResilienceConfig, RetryPolicy, StepFailure, finalize_request)
 from repro.serve.spans import span
 
-# what the sampler's spans and counter put in SampledBatch.meta
-SAMPLE_PARTS = ("expand_s", "gather_s", "upload_s", "upload_bytes")
+# what the sampler's spans and counters put in SampledBatch.meta
+SAMPLE_PARTS = ("expand_s", "gather_s", "upload_s", "upload_bytes",
+                "resident_gather_bytes")
 # the keys every step_log record gains from its spans and counters
 STEP_PARTS = ("refill_s", "sample_s", "forward_s", "scatter_s",
               *SAMPLE_PARTS, "recompiled")
@@ -376,8 +377,9 @@ class HGNNServeEngine:
 
         Each step runs under the ``hgnn.serve.*`` spans
         (``repro.serve.spans``); its ``step_log`` record carries their
-        seconds, the sampler's phases and uploaded bytes, the forward's
-        recompiles, and the ``seq`` of every request it served.
+        seconds, the sampler's phases, its uploaded and device-gathered
+        bytes, the forward's recompiles, and the ``seq`` of every request
+        it served.
         """
         import collections
         import time

@@ -31,6 +31,11 @@ Fan-out caps: per hop, each row keeps the first ``min(fanout, K_table)``
 entries of its precomputed padded row (deterministic; the table itself was
 degree-capped with the model's RNG).  Overflowing a rung truncates the
 *frontier*, farthest hop first — never the targets — and reports the count.
+
+Feature rows never cross the host link per batch: the raw per-type feature
+tables the model's gathers read go on the device once, at construction, and
+each batch uploads only int32 row ids; the rows are gathered there
+(:meth:`_TypeTable.rows`).
 """
 from __future__ import annotations
 
@@ -67,10 +72,11 @@ class SampledBatch:
         return len(self.target_ids)
 
 
-def _pad_ids(ids: np.ndarray, cap: int) -> np.ndarray:
-    out = np.zeros(cap, np.int64)
-    out[: len(ids)] = ids
-    return out
+@jax.jit
+def _take_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
+    """Rows ``ids`` of a device-resident table; an id past its end reads as
+    a row of exact zeros."""
+    return jnp.take(table, ids, axis=0, mode="fill", fill_value=0)
 
 
 class _TypeTable:
@@ -78,7 +84,8 @@ class _TypeTable:
 
     ``identity`` short-circuits the relabeling when the rung cap covers the
     whole type — local ids == global ids and downstream index tables are
-    reused verbatim (the parity path).
+    reused verbatim (the parity path).  ``gathered_bytes`` counts the
+    feature bytes :meth:`rows` gathered on the device.
     """
 
     def __init__(self, n_type: int, cap: int, targets: np.ndarray,
@@ -105,6 +112,7 @@ class _TypeTable:
                 ids = ids[:cap]
             self.ids = ids
         self.n_real = len(self.ids)
+        self.gathered_bytes = 0
         self._lut = np.full(n_type, -1, np.int64)
         self._lut[self.ids] = np.arange(self.n_real)
 
@@ -112,13 +120,17 @@ class _TypeTable:
         """Global -> local; dropped (truncated) ids come back as -1."""
         return self._lut[ids]
 
-    def rows(self, feats: np.ndarray) -> np.ndarray:
-        """The local feature table, zero rows past ``n_real``."""
+    def rows(self, resident: jax.Array, up: Callable) -> jax.Array:
+        """The local feature table on the device, zero rows past ``n_real``:
+        the resident table itself under identity, else its rows gathered on
+        the device by ids uploaded through ``up`` (pads point past the end)."""
         if self.identity:
-            return feats
-        out = np.zeros((self.cap,) + feats.shape[1:], feats.dtype)
-        out[: self.n_real] = feats[self.ids]
-        return out
+            return resident
+        ids = np.full(self.cap, self.n_type, np.int32)
+        ids[: self.n_real] = self.ids
+        self.gathered_bytes += (self.cap * resident.shape[1]
+                                * resident.dtype.itemsize)
+        return _take_rows(resident, up(ids))
 
 
 class HGNNSampler:
@@ -126,8 +138,11 @@ class HGNNSampler:
 
     ``plan.sample`` must be set (models declare it when ``cfg.fanout >= 1``).
     The constructor precomputes the full-graph index tables with the model
-    ``prepare()``'s exact RNG stream; :meth:`sample` then extracts / relabels
-    / rung-pads per request batch — pure numpy until the final device upload.
+    ``prepare()``'s exact RNG stream and puts on the device, once, every
+    feature table the model's gathers read (``resident``: the target type
+    for HAN and GCN, every type for RGCN and MAGNN); :meth:`sample` then
+    extracts / relabels / rung-pads per request batch in numpy, uploads the
+    index tables and row ids, and gathers the feature rows on the device.
     """
 
     def __init__(self, plan: StagePlan, cfg, hg: HeteroGraph):
@@ -148,6 +163,11 @@ class HGNNSampler:
         self.n_target_type = hg.node_counts[self.target]
         self.feat_dims = {t: hg.feat_dim(t) for t in hg.features}
         self._build_full_tables()
+        read = (hg.features if plan.na.kind in ("mean", "instance")
+                else [self.target])
+        self.resident = {t: jax.device_put(np.asarray(hg.features[t],
+                                                      np.float32))
+                         for t in read}
 
     # ------------------------------------------------------------------
     # full-graph tables (prepare()'s exact RNG stream)
@@ -239,10 +259,12 @@ class HGNNSampler:
     def sample(self, targets: np.ndarray, rung: Optional[int] = None,
                max_rung: Optional[int] = None) -> SampledBatch:
         """Expand the targets' frontier and choose a rung, then gather the
-        local tables, each array put on the device as soon as it is built
-        (:func:`_upload`) — each phase under its span
+        local tables: each index array put on the device as soon as it is
+        built (:func:`_upload`), the feature rows gathered on the device
+        from the resident tables — each phase under its span
         (``repro.serve.spans``), timed into ``meta``.  ``gather_s`` leaves
-        out the uploads nested in it."""
+        out the uploads nested in it; ``resident_gather_bytes`` counts the
+        feature bytes gathered on the device."""
         targets = np.asarray(targets, np.int64).reshape(-1)
         if len(targets) and (targets.min() < 0
                              or targets.max() >= self.n_target_type):
@@ -269,6 +291,8 @@ class HGNNSampler:
                 target_rows = (targets.copy() if tt.identity
                                else tt.relabel(targets))
             rec["gather_s"] -= rec["upload_s"]
+            rec["resident_gather_bytes"] = sum(
+                tb.gathered_bytes for tb in tables.values())
         return SampledBatch(
             batch=batch,
             target_ids=targets,
@@ -357,8 +381,8 @@ class HGNNSampler:
         k = self.k_eff
         table = tables[self.target]
         batch: Dict = {
-            "feats": {self.target: up(
-                table.rows(self.hg.features[self.target]))},
+            "feats": {self.target: table.rows(self.resident[self.target],
+                                              up)},
             "feat_dims": {self.target: self.feat_dims[self.target]},
             "n_nodes": table.cap,
             "row_mask": up(self._row_mask(table)),
@@ -488,7 +512,7 @@ class HGNNSampler:
         cfg, plan = self.cfg, self.plan
         k = self.k_eff
         batch: Dict = {
-            "feats": {t: up(tables[t].rows(self.hg.features[t]))
+            "feats": {t: tables[t].rows(self.resident[t], up)
                       for t in self.hg.features},
             "counts": {t: tables[t].cap for t in self.hg.node_counts},
             "feat_dims": dict(self.feat_dims),
@@ -601,7 +625,7 @@ class HGNNSampler:
         i_cap = self.k_eff
         tt = tables[self.target]
         batch: Dict = {
-            "feats": {t: up(tables[t].rows(self.hg.features[t]))
+            "feats": {t: tables[t].rows(self.resident[t], up)
                       for t in tables},
             "feat_dims": {t: self.feat_dims[t] for t in tables},
             "n_nodes": tt.cap,
@@ -678,7 +702,7 @@ class HGNNSampler:
                 idx[e: e + len(loc)] = loc
                 e += len(loc)
         return {
-            "x": up(table.rows(self.hg.features[self.target])),
+            "x": table.rows(self.resident[self.target], up),
             "seg": up(seg),
             "idx": up(idx),
             "n_nodes": table.cap,
@@ -687,11 +711,10 @@ class HGNNSampler:
 
 
 def _upload(x: np.ndarray, rec: Dict) -> jax.Array:
-    """Put one array of a batch on the device under the
-    ``hgnn.sample.upload`` span, adding its seconds and its bytes (padded,
-    as on the device) to ``rec``.  Each array goes up as soon as it is
-    built: one transfer of the whole batch at the end measured slower on
-    a TPU v5e for R-GCN's wide tables (``PERF.md``)."""
+    """Put one host array of a batch — an index table or a type's row ids,
+    never feature rows — on the device under the ``hgnn.sample.upload``
+    span, adding its seconds and its bytes (padded, as on the device) to
+    ``rec``.  Each array goes up as soon as it is built."""
     with span("hgnn.sample.upload", rec, "upload_s"):
         out = jnp.asarray(x)
     rec["upload_bytes"] += out.nbytes

@@ -93,7 +93,10 @@ def test_step_records_split_the_step(tiny_hg, model):
         assert 0 < parts <= r["step_s"]
         assert r["expand_s"] + r["gather_s"] + r["upload_s"] <= r["sample_s"]
         assert min(r["expand_s"], r["gather_s"], r["upload_s"]) > 0
-        assert r["upload_bytes"] >= r["frontier_bytes"] > 0
+        # the tiny graph puts every type on an identity rung: the frontier
+        # rows are already on the device, and only index tables go up
+        assert r["resident_gather_bytes"] == 0
+        assert r["upload_bytes"] > 0 and r["frontier_bytes"] > 0
         assert r["wall_s"] <= r["step_s"]
         assert r["recompiled"] == 0
     assert eng.stats()["compiles_after_warmup"] == 0

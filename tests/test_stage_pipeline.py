@@ -647,7 +647,17 @@ def test_sample_stage_record_rides_stage_records(tiny_hg):
     assert "SAMPLE" in recs["stages"]
     sm = recs["stages"]["SAMPLE"]
     assert sm["n_targets"] == 10 and sm["fanout"] == 4
-    assert sm["upload_bytes"] >= sm["frontier_bytes"] > 0
+    # identity rung: the movie table is the resident one, nothing gathered
+    assert sb.batch["feats"]["M"] is sampler.resident["M"]
+    assert sm["resident_gather_bytes"] == 0 and sm["frontier_bytes"] > 0
+    # a rung that cuts the movies: the frontier rows reach the device by
+    # upload or by the resident gather
+    cut_cfg = _cfg("han", fused=True, fanout=4, sample_ladder=((16, 24),))
+    cut = HGNNSampler(get_model(cut_cfg).plan(), cut_cfg, tiny_hg)
+    cm = cut.sample(np.arange(10)).meta
+    assert cm["resident_gather_bytes"] == 24 * tiny_hg.feat_dim("M") * 4
+    assert cm["upload_bytes"] + cm["resident_gather_bytes"] >= (
+        cm["frontier_bytes"]) > 0
     assert tuple(sm["rung"]) in m.plan().sample.ladder
     # SAMPLE is host-side traffic: the FLOPs/bytes totals still reconcile
     # over the compiled stages only
