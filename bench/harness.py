@@ -6,7 +6,9 @@ configuration file and a traffic mix; the traffic file
 ``bench/traffic/<traffic>.json`` is read by the one generator in
 ``bench/traffic.py``; each per-layer metric is the ``read(ctx)`` function of
 ``bench/metrics/<name>.py``; each cell's limits are in
-``bench/limits/<workload>.json``.
+``bench/limits/<workload>.json``; everything that differs between models
+is in the module ``bench/models/<model>.py`` that the configuration's
+``"model"`` names (:func:`model`).
 
 The program is driven through the entry the ``serve --hgnn`` launcher
 uses: ``HGNNConfig(fused=True)`` with the configuration's widths and every
@@ -56,10 +58,12 @@ def find_cell(name: str, root: Path = ROOT) -> Dict:
     def reports(m):
         return "workloads" not in m or name in m["workloads"]
 
+    cfg = load_json(root / entry["file"])
     return {
         "name": name,
         "chips": int(wl["chips"]),
-        "config": load_json(root / entry["file"]),
+        "config": cfg,
+        "model": model(cfg["model"], root),
         "traffic": load_json(root / "bench" / "traffic"
                              / f"{wl['traffic']}.json"),
         "end_to_end": [m for m in spec["end_to_end"] if reports(m)],
@@ -68,13 +72,48 @@ def find_cell(name: str, root: Path = ROOT) -> Dict:
     }
 
 
-def reader(metric: str, root: Path = ROOT) -> Callable:
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+def _module(path: Path, name: str):
+    mod_spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str, root: Path = ROOT) -> Callable:
+    path = root / "bench" / "metrics" / f"{metric}.py"
+    return _module(path, f"bench_metric_{metric.replace('.', '_')}").read
+
+
+class UnknownModel(LookupError):
+    pass
+
+
+def model(name: str, root: Path = ROOT):
+    """The module ``bench/models/<name>.py``, which gives everything the
+    benchmark knows of one model:
+
+    - ``program_kwargs(cfg)``: the ``HGNNConfig`` arguments beyond the
+      shared widths;
+    - ``weight_shapes(cfg)``: ``{name: shape}`` of the model's own weights
+      (``fp.<type>`` and ``cls`` are :mod:`bench.weights`'s), and
+      ``weight_scale(name, shape)``: each weight's init scale;
+    - ``program_leaf(flat, layer, keys)``: the benchmark weight behind one
+      program leaf (``keys`` its path inside layer ``layer``);
+    - ``forward(be, cfg, w, *args)``: the plain float32 forward, and
+      ``reference_args(cfg, xs, tables, n, edges, cap, pad)``: its
+      ``args``, padded;
+    - ``inputs(g, index, local, cap, full_rows, adjacency)``: the feature
+      tables and the validated edge lists of one batch;
+    - ``row_cap(cfg, spec)``: the neighbors a served row may keep;
+    - ``work(cfg, g)``: the counted work of one full-graph forward,
+      ``{"flops", "bytes", "edges", "fp_flops", "feature_bytes"}``, as
+      :mod:`bench.work` defines it.
+    """
+    path = root / "bench" / "models" / f"{name}.py"
+    if not path.is_file():
+        raise UnknownModel(f"model {name!r} has no module "
+                           f"bench/models/{name}.py (looked for {path})")
+    return _module(path, f"bench_model_{name}")
 
 
 def seed_word(seed: int) -> int:
@@ -183,15 +222,14 @@ def request_class():
 # set-up
 # ---------------------------------------------------------------------------
 
-def program_config(cfg: Dict, spec: Dict, seed: int):
+def program_config(cell: Dict, seed: int):
     from repro.configs.base import HGNNConfig
 
+    cfg, spec = cell["config"], cell["traffic"]
     kw = dict(model=cfg["model"], dataset=cfg["dataset"], fused=True,
               layers=cfg["layers"], hidden=cfg["hidden"],
               n_classes=cfg["n_classes"], max_degree=cfg["max_degree"],
-              seed=seed_word(seed))
-    if cfg["model"] == "han":
-        kw.update(n_heads=cfg["n_heads"], attn_hidden=cfg["attn_hidden"])
+              seed=seed_word(seed), **cell["model"].program_kwargs(cfg))
     if spec["kind"] != "full":
         kw["fanout"] = int(spec["fanout"])
     return HGNNConfig(**kw)
@@ -207,10 +245,10 @@ def set_up(cell: Dict, seed: int, spans: Spans) -> Dict:
     g = graphs.make_graph(cfg["graph"])
     hg = HeteroGraph(dict(g.counts), g.feats, g.relations,
                      name=cfg["dataset"])
-    pcfg = program_config(cfg, spec, seed)
+    pcfg = program_config(cell, seed)
     built = build_hgnn_infer(pcfg, hg, rng=jax.random.key(pcfg.seed))
-    flat = weights.make(cfg, seed)
-    params = weights.to_program(cfg, flat, built.params)
+    flat = weights.make(cell["model"], cfg, seed)
+    params = weights.to_program(cell["model"], flat, built.params)
     out = {"graph": g, "hg": hg, "pcfg": pcfg, "built": built,
            "flat": flat, "params": params}
     if spec["kind"] == "full":
@@ -346,42 +384,26 @@ def _references(cell: Dict, st: Dict, control: bool) -> List:
     (``CONTROLS``), over the benchmark's own weights."""
     w = weights.nested(cell["config"],
                        {k: np.asarray(v) for k, v in st["flat"].items()})
-    return [reference.Reference(cell["config"], w, p)
+    return [reference.Reference(cell["model"], cell["config"], w, p)
             for p in ("highest",) + (CONTROLS if control else ())]
-
-
-def _adjacency(st: Dict, key) -> "graphs.sp.csr_matrix":
-    """The graph's metapath (list key) or relation (tuple key) adjacency,
-    made once per run."""
-    cache = st.setdefault("adjacency", {})
-    k = tuple(key)
-    if k not in cache:
-        cache[k] = (graphs.metapath_adjacency(st["graph"], list(key))
-                    if isinstance(key, list) else
-                    graphs.in_adjacency(st["graph"], k))
-    return cache[k]
 
 
 def _inputs(cell: Dict, st: Dict, index: Dict, local: Dict, cap: int,
             full_rows: bool):
     """Feature tables and validated edge lists (local ids) of one batch:
-    the whole graph (identity ``local``) or one sampled step."""
-    cfg, g = cell["config"], st["graph"]
-    t = g.target
-    if cfg["model"] == "han":
-        n = len(local[t])
-        edges = [reference.validate_edges(d, s, n, n, local[t], local[t],
-                                          _adjacency(st, mp), cap, full_rows)
-                 for (d, s), mp in zip(reference.han_edges(index),
-                                       g.metapaths)]
-        return {t: g.feats[t][local[t]]}, edges
-    rels = {}
-    for key, (d, s) in reference.rgcn_edges(index).items():
-        sk, _, dk = key
-        rels[key] = reference.validate_edges(
-            d, s, len(local[dk]), len(local[sk]), local[dk], local[sk],
-            _adjacency(st, key), cap, full_rows)
-    return {ty: g.feats[ty][local[ty]] for ty in g.counts}, rels
+    the whole graph (identity ``local``) or one sampled step.  The model
+    asks for each adjacency as ``adjacency(fn, key)``, ``fn(graph, key)``
+    made once per run."""
+    cache = st.setdefault("adjacency", {})
+
+    def adjacency(fn, key):
+        k = (fn.__name__, tuple(key))
+        if k not in cache:
+            cache[k] = fn(st["graph"], key)
+        return cache[k]
+
+    return cell["model"].inputs(st["graph"], index, local, cap, full_rows,
+                                adjacency)
 
 
 def check(cell: Dict, st: Dict, win: Dict, seed: int,
@@ -443,7 +465,7 @@ def _served_rows(cell, st, win, seed, refs, rows):
             if step is None or step < 0:
                 raise reference.BadEdges("an answered row with no step")
             need.setdefault(step, []).append((r, i, int(uniq[j])))
-    cap = min(int(cell["traffic"]["fanout"]), cell["config"]["max_degree"])
+    cap = cell["model"].row_cap(cell["config"], cell["traffic"])
     t = st["graph"].target
     got, wants = [], [[] for _ in refs]
     for step, items in sorted(need.items()):
@@ -532,7 +554,7 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
     }
     correct = all(v["value"] <= v["limit"] for v in checks.values())
     ctx = {"window": win, "trace": reduced,
-           "work": (work.forward(cell["config"], st["graph"])
+           "work": (cell["model"].work(cell["config"], st["graph"])
                     if spec["kind"] == "full" else None),
            "peaks": work.peaks(devs[0].device_kind)
            if devs[0].platform == "tpu" else None,
@@ -580,7 +602,7 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace: bool,
 def main(args, t_proc0: float) -> int:
     try:
         cell = find_cell(args.workload)
-    except (KeyError, OSError, ValueError) as e:
+    except (LookupError, OSError, ValueError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
     try:

@@ -5,6 +5,8 @@ from pathlib import Path
 
 import pytest
 
+from bench import harness
+
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
 BENCH = FIXTURES.parents[1]
 
@@ -15,9 +17,10 @@ def tiny_cell(model: str, traffic: str, workload: str) -> dict:
     spec = json.loads((BENCH / "traffic" / f"{traffic}.json").read_text())
     if spec["kind"] == "open":
         spec["rate_per_s"] = 50
+    cfg = json.loads((FIXTURES / f"tiny_{model}.json").read_text())
     return {
-        "name": workload, "chips": 1,
-        "config": json.loads((FIXTURES / f"tiny_{model}.json").read_text()),
+        "name": workload, "chips": 1, "config": cfg,
+        "model": harness.model(cfg["model"]),
         "traffic": spec, "end_to_end": [], "per_layer": [],
         "limits": json.loads((BENCH / "limits"
                               / f"{workload}.json").read_text()),

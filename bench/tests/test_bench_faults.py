@@ -1,7 +1,12 @@
 """A run with the timed path broken underneath comes out not correct: the
 chip check is skipped, everything else of a run is driven at a size the
 CPU holds.  Faults: an answer altered where it is produced; half of the
-batch left out of a mean, the mean taken over the rest."""
+batch left out of a mean, the mean taken over the rest.
+
+A full cell compares every row, so one altered answer is enough there.  A
+serving cell compares a seeded sample of the answered rows, so its fault
+alters every answer: one altered row would be seen only when a sampled
+request asks for it."""
 import time
 
 import jax.numpy as jnp
@@ -18,6 +23,18 @@ def _alter_one_answer(monkeypatch):
     def altered(self, params, z, batch=None):
         out = head(self, params, z, batch)
         return out.at[0, 0].add(1e-3 * jnp.max(jnp.abs(out)))
+
+    monkeypatch.setattr(StageGraphExecutor, "head", altered)
+
+
+def _alter_every_answer(monkeypatch):
+    from repro.core.pipeline import StageGraphExecutor
+
+    head = StageGraphExecutor.head
+
+    def altered(self, params, z, batch=None):
+        out = head(self, params, z, batch)
+        return out.at[:, 0].add(1e-3 * jnp.max(jnp.abs(out)))
 
     monkeypatch.setattr(StageGraphExecutor, "head", altered)
 
@@ -52,7 +69,7 @@ def _half_neighbors_in_mean(monkeypatch):
     ("han", "full", "han_imdb.full", _alter_one_answer),
     ("han", "serve_zipf", "han_imdb.serve_zipf", _half_rows_in_semantic_mean),
     ("rgcn", "full", "rgcn_imdb.full", _half_neighbors_in_mean),
-    ("rgcn", "serve_sat", "rgcn_imdb.serve_sat", _alter_one_answer),
+    ("rgcn", "serve_sat", "rgcn_imdb.serve_sat", _alter_every_answer),
 ])
 def test_broken_timed_path_is_not_correct(cell, monkeypatch, model, traffic,
                                           workload, fault):

@@ -3,7 +3,7 @@ import json
 
 import pytest
 
-from bench import graph, work
+from bench import graph, harness, work
 from bench.tests.conftest import BENCH
 
 
@@ -14,9 +14,13 @@ def imdb():
     return cfgs, graph.make_graph(cfgs["han"]["graph"])
 
 
+def _forward(cfg, g):
+    return harness.model(cfg["model"]).work(cfg, g)
+
+
 def test_han_fp_is_the_target_projection(imdb):
     cfgs, g = imdb
-    w = work.forward(cfgs["han"], g)
+    w = _forward(cfgs["han"], g)
     assert w["fp_flops"] == 2 * 4278 * 3066 * 64
     assert w["feature_bytes"] == 4 * 4278 * 3066
     assert 54e6 < w["bytes"] < 58e6  # about 55 MB: features + edges
@@ -24,7 +28,7 @@ def test_han_fp_is_the_target_projection(imdb):
 
 def test_rgcn_fp_is_5p77_gflop(imdb):
     cfgs, g = imdb
-    w = work.forward(cfgs["rgcn"], g)
+    w = _forward(cfgs["rgcn"], g)
     assert w["fp_flops"] == 2 * 64 * (4278 * 3066 + 2081 * 2081 + 5257 * 5257)
     assert abs(w["fp_flops"] - 5.77e9) < 0.01e9
     assert abs(w["feature_bytes"] - 180.3e6) < 0.1e6
@@ -44,9 +48,9 @@ def test_count_ignores_the_layout_switches(imdb, model):
     """The count reads widths and the graph, never the program's layout:
     the fused and the csr layouts get the same yardstick."""
     cfgs, g = imdb
-    base = work.forward(cfgs[model], g)
+    base = _forward(cfgs[model], g)
     for switch in ({"fused": False}, {"fused": True, "degree_buckets": 3}):
-        assert work.forward({**cfgs[model], **switch}, g) == base
+        assert _forward({**cfgs[model], **switch}, g) == base
 
 
 def test_peaks_carry_their_source_and_refuse_unknown_kinds():

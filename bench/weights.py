@@ -3,57 +3,37 @@ seed, in the benchmark's own layout, then laid into the program's
 parameter tree (:func:`to_program`)."""
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict
 
 import numpy as np
 
 
-def _rel_keys(cfg: Dict) -> List[Tuple[str, str, str]]:
-    keys = []
-    for s, r, d, _n, rev in cfg["graph"]["relations"]:
-        keys += [(s, r, d), (d, rev, s)]
-    return sorted(keys)
-
-
-def shapes(cfg: Dict) -> Dict:
-    """``{name: shape}`` of every weight, in the benchmark's layout."""
+def shapes(model, cfg: Dict) -> Dict:
+    """``{name: shape}`` of every weight, in the benchmark's layout: the
+    per-type input projections ``fp.<type>`` and the head ``cls``, then the
+    weights of ``model`` (a module of ``bench/models/``)."""
     g, d = cfg["graph"], cfg["hidden"]
     out = {f"fp.{t}": (int(g["dims"][t]), d) for t in sorted(g["dims"])}
     out["cls"] = (d, cfg["n_classes"])
-    for l in range(cfg["layers"]):
-        if cfg["model"] == "han":
-            p, heads = len(g["metapaths"]), cfg["n_heads"]
-            if l > 0:
-                out[f"{l}.fp"] = (d, d)
-            out[f"{l}.gat_dst"] = (p, heads, d // heads)
-            out[f"{l}.gat_src"] = (p, heads, d // heads)
-            out[f"{l}.sem_W"] = (d, cfg["attn_hidden"])
-            out[f"{l}.sem_b"] = (cfg["attn_hidden"],)
-            out[f"{l}.sem_q"] = (cfg["attn_hidden"],)
-        else:
-            for key in _rel_keys(cfg):
-                out[f"{l}.w_rel.{'|'.join(key)}"] = (d, d)
-            for t in sorted(g["counts"]):
-                out[f"{l}.w_self.{t}"] = (d, d)
+    out.update(model.weight_shapes(cfg))
     return out
 
 
-def make(cfg: Dict, seed: int) -> Dict:
-    """Flat ``{name: device array}``, float32, N(0, 1/fan_in) (biases
-    N(0, 0.01)), from ``seed`` in one jitted call."""
+def make(model, cfg: Dict, seed: int) -> Dict:
+    """Flat ``{name: device array}``, float32, each N(0, 1) times the
+    model's ``weight_scale``, drawn in sorted name order from ``seed`` in one
+    jitted call."""
     import jax
     import jax.numpy as jnp
 
-    sh = shapes(cfg)
+    sh = shapes(model, cfg)
     names = sorted(sh)
 
     def draw(key):
         out = {}
         for name, k in zip(names, jax.random.split(key, len(names))):
             shape = sh[name]
-            scale = 0.1 if name.endswith("sem_b") else 1.0 / np.sqrt(
-                shape[-1] if name.endswith(("gat_dst", "gat_src", "sem_q"))
-                else shape[0])
+            scale = model.weight_scale(name, shape)
             out[name] = jax.random.normal(k, shape, jnp.float32) * scale
         return out
 
@@ -82,43 +62,32 @@ class LayoutChanged(Exception):
     pass
 
 
-def to_program(cfg: Dict, flat: Dict, like):
+def to_program(model, flat: Dict, like):
     """The program's parameter pytree (``like``, as its ``init`` made it)
-    with every leaf taken from ``flat``.  A leaf the benchmark cannot name,
-    or one whose shape differs, raises: the program's parameter layout has
-    changed and this mapping must follow it."""
+    with every leaf taken from ``flat``: the shared ``fp.<type>`` and
+    ``cls`` here, every other leaf by ``model.program_leaf``.  A leaf the
+    benchmark cannot name, or one whose shape differs, raises: the
+    program's parameter layout has changed and this mapping must follow
+    it."""
     import jax
     import jax.numpy as jnp
     from jax.tree_util import DictKey, SequenceKey
 
-    def name_of(path, leaf):
+    def name_of(path):
         keys = [k.key if isinstance(k, DictKey) else k.idx for k in path
                 if isinstance(k, (DictKey, SequenceKey))]
         layer = 0
         if keys and keys[0] == "layers":
             layer, keys = keys[1] + 1, keys[2:]
-        head = keys[0]
-        if head == "cls":
+        if keys[0] == "cls":
             return flat["cls"]
-        if head == "fp":
-            if layer == 0:
-                return flat[f"fp.{keys[1]}"]
-            return flat[f"{layer}.fp"]
-        if head == "gat":
-            if len(keys) == 2:  # stacked [P, H, Dh]
-                return flat[f"{layer}.gat_{keys[1][2:]}"]
-            return flat[f"{layer}.gat_{keys[2][2:]}"][keys[1]]
-        if head == "sem":
-            return flat[f"{layer}.sem_{keys[1]}"]
-        if head == "w_rel":
-            return flat[f"{layer}.w_rel.{'|'.join(keys[1])}"]
-        if head == "w_self":
-            return flat[f"{layer}.w_self.{keys[1]}"]
-        raise KeyError(path)
+        if keys[0] == "fp" and layer == 0:
+            return flat[f"fp.{keys[1]}"]
+        return model.program_leaf(flat, layer, keys)
 
     def fill(path, leaf):
         try:
-            v = name_of(path, leaf)
+            v = name_of(path)
         except (KeyError, IndexError) as e:
             raise LayoutChanged(f"no benchmark weight for program leaf "
                                 f"{jax.tree_util.keystr(path)}") from e
