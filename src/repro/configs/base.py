@@ -149,10 +149,14 @@ class HGNNConfig:
     dataset: str = "imdb"  # imdb | acm | dblp | reddit
     hidden: int = 64
     n_classes: int = 8
-    n_heads: int = 8  # GAT heads in Neighbor Aggregation
+    # attention heads in Neighbor Aggregation: HAN splits `hidden` over
+    # them; MAGNN's are each `hidden` wide, concatenated to n_heads*hidden
+    n_heads: int = 8
     attn_hidden: int = 128  # semantic-attention hidden dim
     max_degree: int = 64  # padded-neighbor cap (TPU-friendly dense layout)
-    max_instances: int = 16  # MAGNN instances sampled per target node
+    # MAGNN instances sampled per target node on the full-graph path;
+    # 0 keeps every instance (sampled serving then caps at `fanout`)
+    max_instances: int = 16
     # Optimized (beyond-paper / guideline) execution path:
     #   stacked subgraphs (inter-subgraph parallelism), concat-free SA,
     #   optionally the fused GAT-NA / FP+NA kernels.
@@ -161,7 +165,9 @@ class HGNNConfig:
     # Degree-bucketed padded NA layout: >1 bins rows into that many K-caps
     # (core/metapath.py bucket_padded) instead of one K=max_degree pad;
     # 0/1 keeps the single stacked [P, N, K] layout. Fused path only
-    # (HAN's stacked metapaths and RGCN's per-relation tables).
+    # (HAN's stacked metapaths and RGCN's per-relation tables). MAGNN bins
+    # its [N, I, L] instance tables by instance count the same way; there
+    # 0 picks 8 buckets when every instance is kept and 1 keeps one table.
     degree_buckets: int = 0
     # Fused NA→SA epilogue (inter-stage data reuse): the semantic-score
     # pass-1 partial accumulates inside the NA kernel while each z tile is

@@ -9,7 +9,8 @@ observes for DGL.  Produces device-ready layouts:
 * ``CSRSubgraph`` — flat CSR (indptr/indices) for the segment-sum execution
   path (the DGL-faithful baseline we characterize).
 * ``InstanceBatch`` — MAGNN metapath *instances* (node id per path position),
-  sampled with a per-node cap.
+  every instance of every target, or sampled with a per-node cap; the
+  full-graph path bins its rows by instance count (``bucket_padded``).
 
 Stacking: HAN/MAGNN aggregate per metapath then across metapaths.  The
 baseline keeps one subgraph per metapath (and the Semantic Aggregation stage
@@ -19,9 +20,10 @@ rearrangement ever happens on device.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import List, Optional, Sequence
+from dataclasses import dataclass, field
+from typing import List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 import scipy.sparse as sp
 
@@ -86,14 +88,49 @@ class CSRSubgraph:
 
 @dataclass
 class InstanceBatch:
-    """MAGNN metapath instances: ``nodes[i, j]`` = node id at position j of
-    instance i (position 0 = target).  ``types`` gives the node type per
-    position.  Instances are grouped per target: ``[N, I, L+1]`` with mask.
+    """MAGNN metapath instances: ``nodes[v, i, j]`` = node id at position j
+    of target v's instance i (position 0 = the target v itself, position
+    L-1 = the instance's far end).  ``types`` gives the node type per
+    position.  Instances are grouped per target: ``[N, I, L]`` with mask.
+
+    ``n_nodes`` / ``max_degree`` / ``node_path`` let :func:`bucket_padded`
+    bin the rows by instance count as it bins neighbor lists by degree.
     """
 
-    nodes: np.ndarray  # [N, I, L+1] int32
+    nodes: np.ndarray  # [N, I, L] int32
     mask: np.ndarray  # [N, I] float32
     types: List[str]
+
+    @property
+    def n_nodes(self) -> int:
+        return self.nodes.shape[0]
+
+    @property
+    def max_degree(self) -> int:
+        return self.nodes.shape[1]
+
+    @property
+    def node_path(self) -> List[str]:
+        return self.types
+
+    @property
+    def n_instances(self) -> int:
+        return int(self.mask.sum())
+
+
+@jax.tree_util.register_static
+@dataclass(frozen=True)
+class InstanceRecord:
+    """What a full-graph MAGNN ``prepare()`` counted, per metapath: the
+    instances kept and the slots of their padded layout (``slots /
+    instances`` is the layout's padding factor), and the enumeration's
+    seconds.  It rides the prepared batch as static pytree data, so it adds
+    no device argument to a forward; the seconds are left out of equality,
+    so they never key a compile."""
+
+    instances: Tuple[int, ...]
+    instance_slots: Tuple[int, ...]
+    enumerate_s: float = field(default=0.0, compare=False)
 
 
 def build_padded(
@@ -123,15 +160,19 @@ def build_padded(
 
 
 def bucket_padded(
-    sub: PaddedSubgraph, n_buckets: int = 3, round_to: int = 8
+    sub, n_buckets: int = 3, round_to: int = 8
 ) -> DegreeBuckets:
     """Bin a padded subgraph's rows into ``n_buckets`` degree buckets.
 
-    Caps are degree quantiles rounded up to a multiple of ``round_to`` (lane
-    friendliness); the last cap is always ``max_degree`` so no edge is
-    dropped.  Duplicate caps collapse, so fewer buckets than requested can
-    come back (e.g. a degree-uniform graph yields one).
+    ``sub`` is a :class:`PaddedSubgraph` (``nbr [N, K]``) or an
+    :class:`InstanceBatch` (``nodes [N, I, L]``, binned by instance count;
+    each bucket keeps its trailing path axis).  Caps are degree quantiles
+    rounded up to a multiple of ``round_to`` (lane friendliness); the last
+    cap is always ``max_degree`` so no edge is dropped.  Duplicate caps
+    collapse, so fewer buckets than requested can come back (e.g. a
+    degree-uniform graph yields one).
     """
+    table = sub.nodes if isinstance(sub, InstanceBatch) else sub.nbr
     deg = sub.mask.sum(axis=1).astype(np.int64)  # [N]
     caps: List[int] = []
     for i in range(1, n_buckets):
@@ -147,7 +188,7 @@ def bucket_padded(
         if len(rows) == 0:
             continue
         row_ids.append(rows.astype(np.int32))
-        nbrs.append(sub.nbr[rows, :cap])
+        nbrs.append(table[rows, :cap])
         masks.append(sub.mask[rows, :cap])
     return DegreeBuckets(row_ids, nbrs, masks, sub.n_nodes, sub.node_path)
 
@@ -199,16 +240,23 @@ def enumerate_instances(
     rng: Optional[np.random.Generator] = None,
     max_frontier: int = 2_000_000,
 ) -> InstanceBatch:
-    """Sample metapath instances per target node (MAGNN Subgraph Build).
+    """Metapath instances per target node (MAGNN Subgraph Build).
 
-    Full enumeration explodes combinatorially (e.g. DBLP A-P-V-P-A through a
-    20-venue hub); MAGNN implementations sample, and so do we.  Fully
-    vectorized BFS expansion (no per-row Python loop): per hop each partial
-    instance extends by its first ``max_fanout`` CSR neighbors; the frontier
-    is down-sampled to ``max_frontier`` rows between hops; a vectorized
-    per-target reservoir keeps ``max_instances`` instances.
+    ``max_instances <= 0`` is the exact mode: every instance of every
+    target, the returning instance ``(m, d, m)`` included, as the path
+    definition gives it; ``I`` is then the largest instance count.  The
+    rows keep enumeration order (target, then CSR order hop by hop).
+
+    ``max_instances >= 1`` samples, because full enumeration explodes
+    combinatorially on hub-heavy graphs (e.g. DBLP A-P-V-P-A through a
+    20-venue hub): per hop each partial instance extends by its first
+    ``max_fanout`` CSR neighbors; the frontier is down-sampled to
+    ``max_frontier`` rows between hops; a per-target reservoir keeps
+    ``max_instances`` instances.  Both modes are vectorized BFS expansion
+    (no per-row Python loop).
     """
     rng = rng or np.random.default_rng(0)
+    exact = max_instances <= 0
     path = list(node_path)
     n_tgt = hg.node_counts[path[0]]
     frontier = np.arange(n_tgt, dtype=np.int64)[:, None]
@@ -216,7 +264,9 @@ def enumerate_instances(
         adj = hg.rel(a, b).tocsr()
         rows = frontier[:, -1]
         indptr, indices = adj.indptr, adj.indices
-        take = np.minimum(indptr[rows + 1] - indptr[rows], max_fanout)
+        take = indptr[rows + 1] - indptr[rows]
+        if not exact:
+            take = np.minimum(take, max_fanout)
         total = int(take.sum())
         if total == 0:
             frontier = np.zeros((0, frontier.shape[1] + 1), np.int64)
@@ -225,22 +275,23 @@ def enumerate_instances(
         offs = np.arange(total) - np.repeat(np.cumsum(take) - take, take)
         nxt = indices[indptr[rows][rep] + offs].astype(np.int64)
         frontier = np.concatenate([frontier[rep], nxt[:, None]], axis=1)
-        if len(frontier) > max_frontier:  # hub-explosion guard
+        if not exact and len(frontier) > max_frontier:  # hub-explosion guard
             pick = rng.choice(len(frontier), max_frontier, replace=False)
             frontier = frontier[pick]
 
     L = len(path)
-    nodes = np.zeros((n_tgt, max_instances, L), np.int32)
-    mask = np.zeros((n_tgt, max_instances), np.float32)
+    counts = np.bincount(frontier[:, 0], minlength=n_tgt)
+    width = int(counts.max(initial=0)) if exact else max_instances
+    nodes = np.zeros((n_tgt, max(width, 1), L), np.int32)
+    mask = np.zeros((n_tgt, max(width, 1)), np.float32)
     if len(frontier):
-        frontier = frontier[rng.permutation(len(frontier))]
+        if not exact:
+            frontier = frontier[rng.permutation(len(frontier))]
         order = np.argsort(frontier[:, 0], kind="stable")
         f = frontier[order]
-        tgt = f[:, 0]
-        counts = np.bincount(tgt, minlength=n_tgt)
         starts = np.cumsum(counts) - counts
         pos = np.arange(len(f)) - np.repeat(starts, counts)
-        keep = pos < max_instances
+        keep = pos < width
         kept, kpos = f[keep], pos[keep]
         nodes[kept[:, 0], kpos] = kept
         mask[kept[:, 0], kpos] = 1.0

@@ -104,13 +104,23 @@ class StageGraphExecutor:
                 / np.sqrt(d),
             }
         k_fp, k_na, k_sem, k_cls = jax.random.split(rng, 4)
+        d_out = self._out_width()
         params: Dict = {
             "fp": stages.init_feature_projection(k_fp, batch["feat_dims"], d),
-            "cls": jax.random.normal(k_cls, (d, cfg.n_classes), jnp.float32)
-            / np.sqrt(d),
+            "cls": jax.random.normal(k_cls, (d_out, cfg.n_classes),
+                                     jnp.float32) / np.sqrt(d_out),
         }
         params.update(self._init_na_sa(k_na, k_sem, batch))
         return params
+
+    def _out_width(self) -> int:
+        """Width of a layer's SA output: MAGNN concatenates ``n_heads``
+        full-width heads (each ``hidden`` wide); the others keep
+        ``hidden``, HAN splitting it over its heads."""
+        cfg = self.cfg
+        if self.plan.na.kind == "instance":
+            return cfg.n_heads * cfg.hidden
+        return cfg.hidden
 
     def _init_na_sa(self, k_na: jax.Array, k_sem: jax.Array,
                     batch: Dict) -> Dict:
@@ -120,11 +130,11 @@ class StageGraphExecutor:
         identical to the pre-multi-layer init, so layer 0 stays bit-exact."""
         cfg, plan = self.cfg, self.plan
         d = cfg.hidden
-        head_dim = d // cfg.n_heads
         p: Dict = {}
         if plan.na.kind == "gat":
             keys = jax.random.split(k_na, len(plan.metapaths))
-            gat = [stages.init_gat(k, cfg.n_heads, head_dim) for k in keys]
+            gat = [stages.init_gat(k, cfg.n_heads, d // cfg.n_heads)
+                   for k in keys]
             if plan.na.layout == "stacked":
                 # one stacked param set -> ONE kernel launch for the stack
                 # (bucketed keeps the per-metapath list: no uniform K)
@@ -133,13 +143,14 @@ class StageGraphExecutor:
             p["sem"] = semantics.init_semantic_attention(
                 k_sem, d, cfg.attn_hidden)
         elif plan.na.kind == "instance":
-            keys = jax.random.split(k_na, len(plan.metapaths))
+            keys = jax.random.split(k_na, len(plan.metapaths) + 1)
             p["att"] = [
-                stages.init_instance_attention(k, cfg.n_heads, head_dim)
-                for k in keys
+                stages.init_instance_attention(k, cfg.n_heads, d)
+                for k in keys[:-1]
             ]
+            p["rel"] = stages.init_relations(keys[-1], plan.metapaths, d)
             p["sem"] = semantics.init_semantic_attention(
-                k_sem, d, cfg.attn_hidden)
+                k_sem, self._out_width(), cfg.attn_hidden)
         elif plan.na.kind == "mean":
             rel_keys = sorted(batch["rels"])
             rel_ks = jax.random.split(k_na, max(len(rel_keys), 1))
@@ -169,10 +180,14 @@ class StageGraphExecutor:
             p["fp"] = jax.random.normal(k_fp, (d, d), jnp.float32) / np.sqrt(d)
         elif plan.na.kind == "instance":
             # carry is layer-uniform (StagePlan.__post_init__)
+            # the target's carried table is the previous SA output
             types = tuple(sorted(set(plan.layers[0].carry) | {plan.target}))
             fp_ks = jax.random.split(k_fp, len(types))
+            w_in = {t: self._out_width() if t == plan.target else d
+                    for t in types}
             p["fp"] = {
-                t: jax.random.normal(k, (d, d), jnp.float32) / np.sqrt(d)
+                t: jax.random.normal(k, (w_in[t], d), jnp.float32)
+                / np.sqrt(w_in[t])
                 for t, k in zip(types, fp_ks)
             }
         p.update(self._init_na_sa(k_na, k_sem, batch))
@@ -443,49 +458,74 @@ class StageGraphExecutor:
     def _na_instance_one(self, params: Dict, batch: Dict,
                          h: Dict[str, jax.Array], i_path: int) -> jax.Array:
         """One metapath's instance-attention NA — the serial loop body and
-        the async schedule's per-metapath stage share it verbatim."""
+        the async schedule's per-metapath stage share it verbatim.
+
+        The ``instances`` layout is one ``[N, I, L]`` table; ``bucketed``
+        bins the target rows by instance count (``batch["buckets"]``,
+        ``(row_ids, nodes [n_b, I_b, L], mask)`` each) and scatters each
+        bucket's rows back through ``row_ids``.  Every bucket encodes its
+        instances once (``NA.encode``) and every head attends over that
+        one full-width encoding (``NA.attend``)."""
         plan, cfg = self.plan, self.cfg
         specs = stages.HGNN_STAGE_SPECS
-        H = cfg.n_heads
         act = _ACT[plan.na.activation]
         res = batch.get("residency")
         hot = res["hot"] if res is not None and "hot" in res else {}
         p_i = params["att"][i_path]
-        nodes, mask = batch["instances"][i_path]
         types = plan.metapaths[i_path]
-        nodes = stages.shard(nodes, *specs["na_inst_nodes"])
-        mask = stages.shard(mask, *specs["na_nbr"])
-        n, i, l = nodes.shape
-
-        # gather projected features per path position (types are static,
-        # carried by the plan); the residency arm serves the remapped
-        # instance tables through the VMEM-resident cache gather
-        def gather(j):
+        h_t = h[plan.target]
+        if plan.na.layout == "bucketed":
+            tables = batch["buckets"][i_path]
+        else:
+            nodes, mask = batch["instances"][i_path]
+            tables = [(None, nodes, mask)]
+        # gather the rotated projected rows per path position (types are
+        # static, carried by the plan); the residency arm serves the
+        # remapped instance tables through the VMEM-resident cache gather
+        def gather(pool, nodes, j):
             ty = types[j]
             if ty in hot:
                 return _kops().cached_gather(
-                    h[ty], hot[ty], nodes[:, :, j],
+                    pool, hot[ty], nodes[:, :, j],
                     use_pallas=plan.na.use_pallas)
-            return h[ty][nodes[:, :, j]]
+            return pool[nodes[:, :, j]]
 
-        h_path = jnp.stack(
-            [gather(j) for j in range(l)], axis=2
-        )  # [N, I, L, D]
-        h_path = h_path.reshape(n, i, l, H, -1)
-        enc = stages.rotate_encoder(h_path)  # [N, I, H, Dh]
-        h_tgt = h[plan.target].reshape(-1, H, h_path.shape[-1])
-        if plan.na.use_pallas:
-            # Instance attention IS padded GAT NA with the encoded
-            # instances as the source pool (arange neighbor grid).
-            kops = _kops()
-            flat = enc.reshape(n * i, H, enc.shape[-1])
-            nbr_inst = jnp.arange(n * i, dtype=jnp.int32).reshape(n, i)
-            z = kops.gat_aggregate(p_i, h_tgt, flat, nbr_inst, mask,
-                                   use_pallas=True)
-        else:
-            z = stages.instance_aggregate(p_i, h_tgt, enc, mask)
-        z = act(z).reshape(n, -1)
-        return stages.shard(z, *specs["na_flat_out"])  # [N, D]
+        with jax.named_scope("NA.encode"):
+            pools = stages.position_tables(h, params["rel"], types)
+
+        z = jnp.zeros((h_t.shape[0], cfg.n_heads, h_t.shape[-1]), h_t.dtype)
+        for row_ids, nodes, mask in tables:
+            nodes = stages.shard(nodes, *specs["na_inst_nodes"])
+            mask = stages.shard(mask, *specs["na_nbr"])
+            h_dst = h_t if row_ids is None else jnp.take(h_t, row_ids, axis=0)
+            with jax.named_scope("NA.encode"):
+                # position 0 of every instance is its row's own target, so
+                # its rows broadcast from h_dst instead of being gathered
+                enc = stages.rotation_encoder(
+                    [h_dst[:, None, :]]
+                    + [gather(pools[j], nodes, j)
+                       for j in range(1, len(types))])
+            with jax.named_scope("NA.attend"):
+                zb = self._instance_attend(p_i, h_dst, enc, mask)
+            z = zb if row_ids is None else z.at[row_ids].set(zb)
+        z = act(z).reshape(z.shape[0], -1)
+        return stages.shard(z, *specs["na_flat_out"])  # [N, H * D]
+
+    def _instance_attend(self, p_i: Dict, h_dst: jax.Array, enc: jax.Array,
+                         mask: jax.Array) -> jax.Array:
+        """``[n, H, D]`` instance attention of one table or bucket."""
+        if not self.plan.na.use_pallas:
+            return stages.instance_aggregate(p_i, h_dst, enc, mask)
+        # Instance attention IS padded GAT NA with the encoded instances as
+        # the source pool (arange neighbor grid); the kernel takes per-head
+        # rows, so the shared encoding is broadcast over the heads
+        n, i, d = enc.shape
+        heads = p_i["a_dst"].shape[0]
+        flat = jnp.broadcast_to(enc.reshape(n * i, 1, d), (n * i, heads, d))
+        hd = jnp.broadcast_to(h_dst[:, None, :], (n, heads, d))
+        nbr_inst = jnp.arange(n * i, dtype=jnp.int32).reshape(n, i)
+        return _kops().gat_aggregate(p_i, hd, flat, nbr_inst, mask,
+                                     use_pallas=True)
 
     def _na_instance(self, params: Dict, batch: Dict, h: Dict[str, jax.Array]):
         return [self._na_instance_one(params, batch, h, i)
@@ -561,22 +601,20 @@ class StageGraphExecutor:
                 out["|".join(key)] = agg @ params["w_rel"][key]
             return out
         if plan.na.kind == "instance":
-            h_tgt = h_loc[t][:, : part["feats"][t].shape[1]]
-            h_tgt = h_tgt.reshape(h_tgt.shape[0], h_tgt.shape[1], H, -1)
+            h_tgt = h_loc[t][:, : part["feats"][t].shape[1]]  # [K, n, D]
             outs: List[jax.Array] = []
             for p_i, (nodes, mask), types in zip(params["att"],
                                                  part["instances"],
                                                  plan.metapaths):
-                k_, n, i, l = nodes.shape
-                h_path = jnp.stack(
-                    [jax.vmap(lambda hh, idx: hh[idx])(
-                        h_loc[types[j]], nodes[:, :, :, j])
-                     for j in range(l)], axis=3)  # [K, n, I, L, D]
-                h_path = h_path.reshape(k_, n, i, l, H, -1)
-                enc = jax.vmap(stages.rotate_encoder)(h_path)  # [K, n, I, H, Dh]
+                k_, n, _, l = nodes.shape
+                pools = stages.position_tables(h_loc, params["rel"], types)
+                enc = stages.rotation_encoder([
+                    jax.vmap(lambda hh, idx: hh[idx])(
+                        pools[j], nodes[:, :, :, j])
+                    for j in range(l)])  # from [K, n, I, D] per position
                 z = jax.vmap(stages.instance_aggregate, in_axes=(None, 0, 0, 0))(
-                    p_i, h_tgt, enc, mask)
-                outs.append(act(z).reshape(k_, n, -1))  # [K, n, D]
+                    p_i, h_tgt, enc, mask)  # [K, n, H, D]
+                outs.append(act(z).reshape(k_, n, -1))  # [K, n, H * D]
             return outs
         raise ValueError(
             f"no partitioned NA path for kind {plan.na.kind!r}")
@@ -629,11 +667,11 @@ class StageGraphExecutor:
         if plan.na.kind == "instance":
             outs: List = []
             for (nodes, _), types in zip(part["instances"], plan.metapaths):
+                pools = stages.position_tables(h_own, params["rel"], types)
                 outs.append([
-                    jax.vmap(stages.gather_own)(
-                        h_own[types[j]], nodes[:, :, :, j])
+                    jax.vmap(stages.gather_own)(pools[j], nodes[:, :, :, j])
                     for j in range(nodes.shape[3])
-                ])  # per position: [K, n, I, D]
+                ])  # per position, rotated: [K, n, I, D]
             return outs
         raise ValueError(
             f"no partitioned NA split for kind {plan.na.kind!r}")
@@ -692,25 +730,23 @@ class StageGraphExecutor:
                 out["|".join(key)] = agg @ params["w_rel"][key]
             return out
         if plan.na.kind == "instance":
-            h_tgt = h_own[t]
-            h_tgt = h_tgt.reshape(h_tgt.shape[0], h_tgt.shape[1], H, -1)
+            h_tgt = h_own[t]  # [K, n, D]
             outs: List[jax.Array] = []
             for p_i, (nodes, mask), types, pre_i in zip(params["att"],
                                                         part["instances"],
                                                         plan.metapaths, pre):
-                k_, n, i, l = nodes.shape
-                h_path = jnp.stack([
+                k_, n, _, l = nodes.shape
+                pools = stages.position_tables(halos, params["rel"], types)
+                enc = stages.rotation_encoder([
                     jax.vmap(
                         lambda ho, hl, nn, ty=types[j]: stages.gather_merge(
                             ho, hl, nn, part["feats"][ty].shape[1])
-                    )(pre_i[j], halos[types[j]], nodes[:, :, :, j])
+                    )(pre_i[j], pools[j], nodes[:, :, :, j])
                     for j in range(l)
-                ], axis=3)  # [K, n, I, L, D]
-                h_path = h_path.reshape(k_, n, i, l, H, -1)
-                enc = jax.vmap(stages.rotate_encoder)(h_path)
+                ])  # from [K, n, I, D] per position
                 z = jax.vmap(stages.instance_aggregate,
                              in_axes=(None, 0, 0, 0))(p_i, h_tgt, enc, mask)
-                outs.append(act(z).reshape(k_, n, -1))  # [K, n, D]
+                outs.append(act(z).reshape(k_, n, -1))  # [K, n, H * D]
             return outs
         raise ValueError(
             f"no partitioned NA split for kind {plan.na.kind!r}")

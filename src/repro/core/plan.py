@@ -34,9 +34,10 @@ na.kind   gat        multi-head GAT attention (HAN)
           gcn        homogeneous 2-layer mean aggregation (GCN)
 na.layout csr        DGL-faithful flat edge lists (baseline)
           stacked    padded ``[P, N, K]`` stack, one launch / metapath stack
-          bucketed   degree-bucketed padded tiles (per metapath / relation)
+          bucketed   degree-bucketed padded tiles (per metapath / relation;
+                     MAGNN: instance tables binned by instance count)
           padded     padded ``[N, K]`` per relation (RGCN fused)
-          instances  sampled ``[N, I, L]`` instance tables (MAGNN)
+          instances  one ``[N, I, L]`` instance table per metapath (MAGNN)
 sa.kind   attention  HAN-style semantic attention over the stack
           rel_sum    RGCN sum across relations + self loop
           none       single semantic — identity
@@ -394,6 +395,9 @@ INSTANCE_BATCH_SPECS: Tuple[ShardRule, ...] = (
     ("instances", 3, (BATCH, None, None)),  # [N, I, L] instance node tables
     ("instances", 2, (BATCH, None)),  # [N, I] instance masks
 )
+INSTANCE_BUCKET_BATCH_SPECS: Tuple[ShardRule, ...] = (
+    ("buckets", 3, (BATCH, None, None)),  # per-bucket nodes [n_b, I_b, L]
+) + BUCKETED_BATCH_SPECS  # masks [n_b, I_b], row_ids [n_b]
 # Partitioned batches: every table under batch["part"] leads with the
 # partition dim K, which shards over the BATCH axes (one partition — or a
 # contiguous block of partitions — per data-parallel shard).  The 1-d

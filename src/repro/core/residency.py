@@ -44,7 +44,7 @@ executor's dispatch arms and ``kernels/feature_cache.cached_gather``.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Iterator, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -103,13 +103,23 @@ def _iter_gathers(plan: StagePlan, batch: Dict) -> Iterator[Tuple]:
             else:  # padded
                 yield s, rel[0], rel[1]
     elif kind == "instance":
-        for (nodes, mask), types in zip(batch["instances"], plan.metapaths):
-            for j, ty in enumerate(types):
-                yield ty, nodes[..., j], mask
+        for tables, types in zip(_instance_tables(plan, batch),
+                                 plan.metapaths):
+            for nodes, mask in tables:
+                for j, ty in enumerate(types):
+                    yield ty, nodes[..., j], mask
     elif kind == "gcn":
         yield plan.target, batch["idx"], None
     else:  # pragma: no cover - plan validation catches this earlier
         raise ValueError(f"no residency gather walk for NA kind {kind!r}")
+
+
+def _instance_tables(plan: StagePlan, batch: Dict) -> List[List[Tuple]]:
+    """Per metapath, its MAGNN instance ``(nodes, mask)`` tables: one, or
+    one per instance-count bucket."""
+    if plan.na.layout == "bucketed":
+        return [[(n, m) for _rid, n, m in bks] for bks in batch["buckets"]]
+    return [[t] for t in batch["instances"]]
 
 
 @dataclass
@@ -214,14 +224,22 @@ def apply(plan: StagePlan, batch: Dict, tables: ResidencyTables) -> Dict:
                 rels[key] = (remap(s, rel[0]), rel[1])
         out["rels"] = rels
     elif kind == "instance":
-        inst = []
-        for (nodes, mask), types in zip(batch["instances"], plan.metapaths):
+        def remap_nodes(nodes, types):
             nn = np.asarray(nodes).copy()
             for j, ty in enumerate(types):
                 if ty in lut:
                     nn[..., j] = lut[ty][nn[..., j]]
-            inst.append((jnp.asarray(nn), mask))
-        out["instances"] = inst
+            return jnp.asarray(nn)
+
+        if layout == "bucketed":
+            out["buckets"] = [
+                [(rid, remap_nodes(n, types), m) for rid, n, m in bks]
+                for bks, types in zip(batch["buckets"], plan.metapaths)]
+        else:
+            out["instances"] = [
+                (remap_nodes(n, types), m)
+                for (n, m), types in zip(batch["instances"],
+                                         plan.metapaths)]
     elif kind == "gcn":
         out["idx"] = remap(plan.target, batch["idx"])
     out["residency"] = {

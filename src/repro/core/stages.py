@@ -322,43 +322,116 @@ def csr_to_edges(indptr: np.ndarray, indices: np.ndarray) -> Tuple[np.ndarray, n
 # ---------------------------------------------------------------------------
 
 
-def init_instance_attention(rng: jax.Array, n_heads: int, head_dim: int):
-    return init_gat(rng, n_heads, head_dim)
+def init_instance_attention(rng: jax.Array, n_heads: int, width: int):
+    """MAGNN's intra-metapath attention: ``n_heads`` heads, each scoring
+    the full-width target row and instance encoding (``a_dst``/``a_src``
+    ``[H, width]``, the two halves of the paper's ``a_k``)."""
+    return init_gat(rng, n_heads, width)
 
 
-def rotate_encoder(h_path: jax.Array) -> jax.Array:
-    """MAGNN's relational rotation (RotatE-style) instance encoder.
+def instance_relations(metapaths) -> Tuple[Tuple[str, str], ...]:
+    """The base relations of MAGNN's rotation encoder, in first-seen order.
 
-    ``h_path``: [N, I, L, H, Dh] projected features along each instance.
-    Treats feature pairs as complex numbers and composes positions by
-    rotation, then averages. Falls back to mean when L == 1.
+    Walking each metapath from its far end toward the target, an edge type
+    ``a -> b`` whose pair has not been seen becomes a base relation with a
+    vector of its own; ``b -> a`` later rotates by that vector's conjugate.
+    On IMDB's MDM and MAM the base relations are ``M -> D`` and ``M -> A``.
     """
-    n, i, l, h, dh = h_path.shape
-    re, im = h_path[..., 0::2], h_path[..., 1::2]
-    # cumulative rotation along the path
-    acc_re, acc_im = re[:, :, 0], im[:, :, 0]
-    out_re, out_im = acc_re, acc_im
-    for pos in range(1, l):
-        r, s = re[:, :, pos], im[:, :, pos]
-        acc_re, acc_im = acc_re * r - acc_im * s, acc_re * s + acc_im * r
-        out_re = out_re + acc_re
-        out_im = out_im + acc_im
-    out = jnp.stack([out_re / l, out_im / l], axis=-1).reshape(n, i, h, dh)
-    return out
+    base: List[Tuple[str, str]] = []
+    for path in metapaths:
+        for j in range(len(path) - 2, -1, -1):
+            a, b = path[j + 1], path[j]
+            if (a, b) not in base and (b, a) not in base:
+                base.append((a, b))
+    return tuple(base)
+
+
+def init_relations(rng: jax.Array, metapaths, width: int) -> Dict[str, jax.Array]:
+    """One relation vector per base relation, ``[width/2, 2]`` (real and
+    imaginary parts), keyed ``"a|b"``; the encoder normalizes each complex
+    entry to unit modulus."""
+    keys = [f"{a}|{b}" for a, b in instance_relations(metapaths)]
+    ks = jax.random.split(rng, len(keys))
+    return {key: jax.random.normal(k, (width // 2, 2), jnp.float32)
+            for key, k in zip(keys, ks)}
+
+
+def edge_rotation(rel: Dict[str, jax.Array], a: str, b: str) -> jax.Array:
+    """``[D/2, 2]`` unit-modulus rotation of the edge ``a -> b``: the base
+    relation's vector, or its conjugate for the reverse edge."""
+    if f"{a}|{b}" in rel:
+        r = rel[f"{a}|{b}"]
+    else:
+        r = rel[f"{b}|{a}"] * jnp.asarray([1.0, -1.0], jnp.float32)
+    return r / jnp.sqrt((r * r).sum(-1, keepdims=True))
+
+
+def rotate(x: jax.Array, r: jax.Array) -> jax.Array:
+    """Complex product ``x ⊙ r``: ``x [..., D]`` read as ``D/2`` pairs
+    (real, imaginary), ``r [D/2, 2]``."""
+    c = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    re, im = c[..., 0], c[..., 1]
+    rr, ri = r[..., 0], r[..., 1]
+    return jnp.stack([re * rr - im * ri, re * ri + im * rr],
+                     axis=-1).reshape(x.shape)
+
+
+def position_rotations(rel: Dict[str, jax.Array], types) -> jax.Array:
+    """``[L, D/2, 2]``: entry ``p`` turns path position ``p`` (``p`` edges
+    from the target) into the target's frame, the product of the
+    rotations of the edges between them (entry 0 is 1).  With the
+    paper's indexing ``t_0 = u, ..., t_n = v`` this is ``prod_{j > i}
+    r_j``, the closed form of the recursion ``o_i = h_{t_i} + o_{i-1} ⊙
+    r_i``."""
+    r0 = next(iter(rel.values()))
+    out = [jnp.zeros_like(r0).at[:, 0].set(1.0)]
+    for p in range(1, len(types)):
+        r = edge_rotation(rel, types[p], types[p - 1])
+        out.append(rotate(out[-1].reshape(-1), r).reshape(r.shape))
+    return jnp.stack(out)
+
+
+def position_tables(tables: Dict[str, jax.Array], rel: Dict[str, jax.Array],
+                    types) -> List[jax.Array]:
+    """Per path position, its type's projected table (``tables[type]``,
+    rows on the second-to-last axis) rotated into the target's frame by
+    :func:`position_rotations`; position 0, the target, as it is."""
+    rot = position_rotations(rel, types)
+    return [tables[types[0]]] + [rotate(tables[ty], r)
+                                 for ty, r in zip(types[1:], rot[1:])]
+
+
+def rotation_encoder(rotated: List[jax.Array]) -> jax.Array:
+    """MAGNN's relational-rotation instance encoding (Fu et al. §4.2):
+    ``h_P = (1/L) sum_p h_{t_p} ⊙ c_p`` from each position's rows already
+    rotated by :func:`position_rotations` (``rotated[p]``, ``[..., D]``).
+    Rotation is per row, so rotating a projected table before the gather
+    rotates every row it serves: one pass over each small table instead of
+    a complex product per instance.  Every attention head reads this one
+    ``[..., D]`` encoding."""
+    out = rotated[0]
+    for x in rotated[1:]:
+        out = out + x
+    return out / len(rotated)
 
 
 def instance_aggregate(
     p: Dict[str, jax.Array],
-    h_tgt: jax.Array,  # [N, H, Dh]
-    enc: jax.Array,  # [N, I, H, Dh] encoded instances
+    h_tgt: jax.Array,  # [N, D] projected target rows
+    enc: jax.Array,  # [N, I, D] encoded instances, shared by every head
     mask: jax.Array,  # [N, I]
 ) -> jax.Array:
-    """Attention over metapath instances per target node -> [N, H, Dh]."""
-    e_t = (h_tgt * p["a_dst"]).sum(-1)  # [N, H]
-    e_i = (enc * p["a_src"]).sum(-1)  # [N, I, H]
-    e = _leaky_relu(e_t[:, None, :] + e_i)
-    e = jnp.where(mask[..., None] > 0, e, -1e9)
-    e = e - jax.lax.stop_gradient(e.max(axis=1, keepdims=True))
-    w = jnp.exp(e) * mask[..., None]
-    alpha = w / jnp.maximum(w.sum(axis=1, keepdims=True), 1e-9)
-    return jnp.einsum("nih,nihd->nhd", alpha, enc)
+    """Per-head attention over each target's instances -> ``[N, H, D]``:
+    ``e_k = LeakyReLU(a_dst_k · h_v + a_src_k · h_P)`` softmaxed over the
+    instances of v, each head a weighted sum of the full-width encodings.
+    A row with no instance aggregates to zero."""
+    # scores laid out [N, H, I]: the softmax runs along the instance axis
+    e_t = h_tgt @ p["a_dst"].T  # [N, H]
+    e_i = jnp.einsum("nid,hd->nhi", enc, p["a_src"])  # [N, H, I]
+    e = _leaky_relu(e_t[:, :, None] + e_i)
+    valid = mask[:, None, :] > 0
+    e = jnp.where(valid, e, -1e9)
+    e = e - jax.lax.stop_gradient(e.max(axis=2, keepdims=True))
+    w = jnp.where(valid, jnp.exp(e), 0.0)
+    alpha = w / jnp.maximum(w.sum(axis=2, keepdims=True), 1e-9)
+    return jnp.einsum("nhi,nid->nhd", alpha, enc)

@@ -294,10 +294,11 @@ def finalize_request(r, status: str, n_classes: int,
     target (duplicates fanned out).  ``PARTIAL``/``FAILED``: only rows
     whose unique id was served survive, compacted in request order, with
     ``served`` naming the target ids those rows answer.  Stamps
-    ``finished_at`` (``perf_counter``).
+    ``finished_at`` (``perf_counter``) where the status turns terminal,
+    after the buffer work, so the stamp and the status agree.
     """
-    r.finished_at = time.perf_counter()
     if r._serve_ids is None:  # rejected/degenerate: already finalized
+        r.finished_at = time.perf_counter()
         r.status = status
         if error is not None:
             r.error = error
@@ -312,6 +313,7 @@ def finalize_request(r, status: str, n_classes: int,
         mask = r._inv < done
         r.logits = buf[r._inv[mask]]
         r.served = np.asarray(r.targets).reshape(-1)[mask]
+    r.finished_at = time.perf_counter()
     r.status = status
     if error is not None:
         r.error = error

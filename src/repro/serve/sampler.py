@@ -210,9 +210,14 @@ class HGNNSampler:
                     for key, (nbr, mask) in self.rel_tables.items()
                 }
         elif kind == "instance":  # MAGNN
-            self.k_eff = min(self.spec.fanout, cfg.max_instances)
+            from repro.core.models.magnn import sample_cap
+
+            # serving keeps a per-target sample even where the full-graph
+            # path keeps every instance (max_instances=0)
+            self.k_eff = sample_cap(cfg)
             self.insts = [
-                mp.enumerate_instances(self.hg, list(p), cfg.max_instances,
+                mp.enumerate_instances(self.hg, list(p),
+                                       cfg.max_instances or self.k_eff,
                                        rng=rng)
                 for p in plan.metapaths
             ]
@@ -621,7 +626,7 @@ class HGNNSampler:
 
     def _gather_instance(self, tables: Dict[str, _TypeTable], up: Callable,
                          ) -> Dict:
-        plan, cfg = self.plan, self.cfg
+        plan = self.plan
         i_cap = self.k_eff
         tt = tables[self.target]
         batch: Dict = {
@@ -633,7 +638,7 @@ class HGNNSampler:
         }
         instances = []
         for ib, p in zip(self.insts, plan.metapaths):
-            if tt.identity and i_cap == cfg.max_instances and all(
+            if tt.identity and i_cap == ib.max_degree and all(
                     tables[ty].identity for ty in p):
                 nodes, mask = ib.nodes, ib.mask
             else:
@@ -650,7 +655,12 @@ class HGNNSampler:
                 mask[: tt.n_real] = src_mask
                 nodes[mask == 0] = 0
             instances.append((up(nodes), up(mask)))
-        batch["instances"] = instances
+        if plan.na.layout == "bucketed":
+            # one rung-shaped bucket holding every row
+            rows = up(np.arange(tt.cap, dtype=np.int32))
+            batch["buckets"] = [[(rows, n, m)] for n, m in instances]
+        else:
+            batch["instances"] = instances
         return batch
 
     # ------------------------------------------------------------------
