@@ -625,8 +625,7 @@ class StageGraphExecutor:
     # Serial partitioned NA gathers from concat(own, halo) — it cannot
     # start until the exchange lands.  But a gather is a pure row
     # selection, so it splits at the *gather*, never at a float
-    # reduction: the owned-side rows (and the per-row source attention
-    # scores, which are row-local EW math) pre-gather against the owned
+    # reduction: the owned-side rows pre-gather against the owned
     # table alone while the exchange is still in flight, and the merge
     # where-selects the halo side in afterwards (stages.gather_own /
     # gather_merge — bitwise equal to the concat-then-gather).  All the
@@ -638,25 +637,15 @@ class StageGraphExecutor:
         from FP's output alone (depends only on FP — runs concurrently
         with ``halo_exchange``).  Returns the pre-gathered operand pytree
         :meth:`_na_partitioned_merge` consumes."""
-        plan, cfg = self.plan, self.cfg
+        plan = self.plan
         part = batch["part"]
         t = plan.target
-        H = cfg.n_heads
         if plan.na.kind == "gat":
-            heads = lambda x: x.reshape(x.shape[0], x.shape[1], H, -1)
-            hs_own = heads(h_own[t])  # [K, n, H, Dh]
+            def one_part(hs_k, nbr_k):  # hs_k [n, D], nbr_k [P, n, Kd]
+                return jax.vmap(lambda nn: stages.gather_own(hs_k, nn))(nbr_k)
 
-            def one_part(hs_k, nbr_k):  # nbr_k [P, n, Kd]
-                def one_path(pp, nn):
-                    e_tab = (hs_k * pp["a_src"]).sum(-1)  # [n, H] EW
-                    return (stages.gather_own(hs_k, nn),
-                            stages.gather_own(e_tab, nn))
-
-                return jax.vmap(one_path)(params["gat"], nbr_k)
-
-            hn_own, e_own = jax.vmap(one_part)(hs_own, part["nbr"])
-            return {"hn": hn_own,  # [K, P, n, Kd, H, Dh]
-                    "e": e_own}  # [K, P, n, Kd, H]
+            # flat rows, as gat_aggregate_padded gathers them
+            return jax.vmap(one_part)(h_own[t], part["nbr"])  # [K,P,n,Kd,D]
         if plan.na.kind == "mean":
             out: Dict = {}
             for key in sorted(part["rels"]):
@@ -691,21 +680,17 @@ class StageGraphExecutor:
             n_own = part["feats"][t].shape[1]
             heads = lambda x: x.reshape(x.shape[0], x.shape[1], H, -1)
             hd = heads(h_own[t])  # [K, n, H, Dh] owned rows ARE the dsts
-            hs_halo = heads(halos[t])  # [K, h_max, H, Dh]
 
-            def one_part(hd_k, hh_k, nbr_k, mask_k, hno_k, eo_k):
-                def one_path(pp, nn, mm, hno, eo):
+            def one_part(hd_k, hh_k, nbr_k, mask_k, hno_k):  # hh_k [h_max, D]
+                def one_path(pp, nn, mm, hno):
                     hn = stages.gather_merge(hno, hh_k, nn, n_own)
-                    e_tab_h = (hh_k * pp["a_src"]).sum(-1)  # [h_max, H]
-                    e_nbr = stages.gather_merge(eo, e_tab_h, nn, n_own)
                     return stages.gat_aggregate_padded(
-                        pp, hd_k, None, None, mm, hn=hn, e_nbr=e_nbr)
+                        pp, hd_k, None, None, mm, hn=hn)
 
-                return jax.vmap(one_path)(params["gat"], nbr_k, mask_k,
-                                          hno_k, eo_k)
+                return jax.vmap(one_path)(params["gat"], nbr_k, mask_k, hno_k)
 
-            z = jax.vmap(one_part)(hd, hs_halo, part["nbr"], part["mask"],
-                                   pre["hn"], pre["e"])
+            z = jax.vmap(one_part)(hd, halos[t], part["nbr"], part["mask"],
+                                   pre)
             z = act(z)  # [K, P, n, H, Dh]
             z = z.reshape(z.shape[0], z.shape[1], z.shape[2], -1)
             return stages.shard(z, BATCH, None, None, None)  # [K, P, n, D]

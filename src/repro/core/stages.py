@@ -100,35 +100,54 @@ def _leaky_relu(x, slope=0.2):
     return jnp.where(x >= 0, x, slope * x)
 
 
+def head_matrices(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``a [H, Dh]`` -> the block-diagonal ``[H*Dh, H]`` matrix whose
+    product with a flat ``[.., H*Dh]`` row gives each head's score
+    ``sum_d x[h, d] a[h, d]``, and the 0/1 ``[H, H*Dh]`` matrix that
+    spreads a per-head value over its head's ``Dh`` lanes."""
+    n_heads, head_dim = a.shape
+    eye = jnp.eye(n_heads, dtype=a.dtype)
+    score = (a[:, :, None] * eye[:, None, :]).reshape(n_heads * head_dim,
+                                                        n_heads)
+    return score, jnp.repeat(eye, head_dim, axis=1)
+
+
 def gat_aggregate_padded(
     p: Dict[str, jax.Array],
     h_dst: jax.Array,  # [N, H, Dh] projected features of target nodes
     h_src: Optional[jax.Array],  # [M, H, Dh] projected neighbor pool
-    nbr: jax.Array,  # [N, K] int32 (may be None when hn/e_nbr pre-gathered)
+    nbr: jax.Array,  # [N, K] int32 (may be None when hn is pre-gathered)
     mask: jax.Array,  # [N, K] float
-    hn: Optional[jax.Array] = None,  # [N, K, H, Dh] pre-gathered neighbors
-    e_nbr: Optional[jax.Array] = None,  # [N, K, H] pre-gathered src scores
+    hn: Optional[jax.Array] = None,  # [N, K, H*Dh] pre-gathered neighbors
 ) -> jax.Array:
     """GAT neighbor aggregation over a padded subgraph. Returns [N, H, Dh].
 
-    ``hn`` / ``e_nbr`` let the async schedule's split stages supply the two
-    TB gathers pre-merged (owned rows gathered while the halo exchange was
-    still in flight, where-selected against the halo rows afterwards) —
-    pure row selections, so the attention math below is bitwise identical
-    to the gather-from-``h_src`` default.
+    Each neighbor is gathered once as one flat ``H*Dh``-wide row of the
+    source pool, and the attention runs on those rows as they are: the
+    source scores are one matmul with the block-diagonal ``a_src``, and
+    ``alpha`` spreads over each head's lanes by a 0/1 matmul (exact at
+    ``highest``), so no ``[H, Dh]`` block is ever laid out per neighbor.
+    ``hn`` lets the async schedule's split stages supply the gather
+    pre-merged (owned rows gathered while the halo exchange was still in
+    flight, where-selected against the halo rows afterwards) — pure row
+    selections, so the math below is bitwise identical to the
+    gather-from-``h_src`` default.
     """
+    n, n_heads, head_dim = h_dst.shape
+    a_src, spread = head_matrices(p["a_src"])
+    hi = jax.lax.Precision.HIGHEST
     e_dst = (h_dst * p["a_dst"]).sum(-1)  # [N, H]   EW
     if hn is None:
-        hn = h_src[nbr]  # [N, K, H, Dh]  TB gather
-    if e_nbr is None:
-        e_nbr = (h_src * p["a_src"]).sum(-1)[nbr]  # [M, H] EW -> [N, K, H]
+        hn = h_src.reshape(h_src.shape[0], -1)[nbr]  # [N, K, H*Dh] TB gather
+    e_nbr = jnp.dot(hn, a_src, precision=hi)  # [N, K, H] source scores
     e = _leaky_relu(e_dst[:, None, :] + e_nbr)  # [N, K, H]
     e = jnp.where(mask[..., None] > 0, e, -1e9)
     e = e - jax.lax.stop_gradient(e.max(axis=1, keepdims=True))
     w = jnp.exp(e) * mask[..., None]
     alpha = w / jnp.maximum(w.sum(axis=1, keepdims=True), 1e-9)  # [N, K, H]
-    out = jnp.einsum("nkh,nkhd->nhd", alpha, hn)  # reduction tree
-    return out
+    lanes = jnp.dot(alpha, spread, precision=hi)  # [N, K, H*Dh]
+    out = (lanes * hn).sum(axis=1)  # reduction tree over K
+    return out.reshape(n, n_heads, head_dim)
 
 
 def gat_aggregate_csr(

@@ -10,6 +10,7 @@ lower.  Widths are HAN/RGCN on IMDB: 4278 movies, 8 heads x 8 dims, K=64
 neighbors, 2 metapaths, a 128-wide semantic-attention hidden layer.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import stages
 from repro.kernels import (feature_cache, fused_fp_na, gat_na, ops,
                            segment_spmm, semantic_attn, streaming)
 
@@ -80,6 +82,28 @@ def test_gat_na_resident_table_at_vmem_budget(one_chip):
     args = list(GAT_ARGS)
     args[3] = ((m, H, DH), F32)
     assert "tpu_custom_call" in _hlo(fn, one_chip, *args)
+
+
+def test_xla_gat_na_gathers_each_neighbor_once_as_a_flat_row(one_chip):
+    """HAN's default NA (the XLA body, no Pallas) at IMDB widths: one
+    gather over the P*N*K neighbor slots, of 64-wide rows, never an
+    ``[H, Dh] = [8, 8]`` block per neighbor (each its own (8, 128) tile,
+    16x padded), and temporaries far under the 2.5 GB that form took."""
+    def fn(ad, as_, hd, hs, nbr, mask):
+        z = stages.gat_aggregate_padded_stacked(
+            {"a_dst": ad, "a_src": as_}, hd, nbr, mask, h_src=hs)
+        return jax.nn.elu(z)
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in GAT_ARGS]
+    compiled = jax.jit(fn).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo
+    slots = rf"\[(?:{P * N * K}|{P},{N},{K})"  # leading dims of a slot array
+    gathers = re.findall(rf"{slots}[\d,]*\]\S* gather\(", hlo)
+    assert len(gathers) == 1, gathers
+    assert not re.search(rf"{slots},{H},{DH}\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
 @pytest.mark.parametrize("block_m", [0, 512], ids=["resident", "streaming"])
