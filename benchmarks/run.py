@@ -3,7 +3,7 @@ Prints ``name,us_per_call,derived`` CSV (benchmarks/common.py contract).
 
 When the HGNN trajectory modules run (``bench_stage_breakdown``,
 ``bench_na_fused``, ``bench_sa_epilogue``, ``bench_partition``,
-``bench_layers``, ``bench_serving`` and/or ``bench_overlap``), their rows
+``bench_layers`` and/or ``bench_serving``), their rows
 are also folded into
 ``BENCH_hgnn.json`` at the repo root — the machine-readable perf baseline
 future PRs diff against (per-stage wall + characterization breakdown,
@@ -47,7 +47,6 @@ MODULES = [
     "bench_serving",             # request-path slot serving: sampled minibatch
     "bench_resilience",          # seeded chaos: retries/degrade/shed/failover
     "bench_residency",           # hot-row cache: hit-rate vs NA HBM bytes
-    "bench_overlap",             # async stage DAG: critical-path vs serial
     "bench_lm_roofline",         # 40-cell arch x shape roofline table
 ]
 
@@ -198,29 +197,6 @@ def parse_residency(rows) -> dict:
     return out
 
 
-def parse_overlap(rows) -> dict:
-    """``overlap/<model>/<ds>/<case>/(dag|parity|accounting)`` rows ->
-    {case: record}.
-
-    The DAG counters and the bit-exactness flag are plan-derived
-    deterministic output (``--check`` compares them EXACTLY); the
-    critical-path / serial-sum accounting walls are recorded for the
-    handbook but never gated."""
-    out: dict = {}
-    for name, us, derived in rows or []:
-        m = re.fullmatch(r"overlap/(\w+)/(\w+)/(\w+)/(dag|parity|accounting)",
-                         name)
-        if not m:
-            continue
-        rec = out.setdefault(f"{m.group(1)}/{m.group(2)}/{m.group(3)}", {})
-        d = dict(kv.split("=", 1) for kv in derived.split())
-        if m.group(4) == "accounting":
-            rec.update({k: round(float(v), 1) for k, v in d.items()})
-        else:
-            rec.update({k: int(v) for k, v in d.items()})
-    return out
-
-
 def check_regression(results: dict, threshold: float = 0.20) -> None:
     """Bench-regression gate: diff the fresh NA/SA stage costs against the
     committed ``BENCH_hgnn.json``; fail on >``threshold`` regression.
@@ -244,9 +220,8 @@ def check_regression(results: dict, threshold: float = 0.20) -> None:
     sv = results.get("bench_serving")
     rz = results.get("bench_resilience")
     rd = results.get("bench_residency")
-    ov = results.get("bench_overlap")
-    if (not sb and not pt and not ly and not sv and not rz and not rd
-            and not ov) or not BENCH_JSON.exists():
+    if (not sb and not pt and not ly and not sv and not rz
+            and not rd) or not BENCH_JSON.exists():
         return
     try:
         committed = json.loads(BENCH_JSON.read_text())
@@ -464,34 +439,6 @@ def check_regression(results: dict, threshold: float = 0.20) -> None:
                     f"residency/{case} na_hbm_bytes: {pv:.3g} -> "
                     f"{rec['na_hbm_bytes']:.3g} "
                     f"(+{100 * (rec['na_hbm_bytes'] / pv - 1):.0f}%)")
-    if ov:
-        # overlap gate: the stage DAG is a pure function of the plan and
-        # the bit-exactness flag must never drop, so both compare at EXACT
-        # equality; the critical-path / serial-sum walls stay ungated as
-        # everywhere else.
-        old_ov = committed.get("overlap", {})
-        fresh_ov = parse_overlap(ov)
-        if not fresh_ov and old_ov:
-            regressions.append("bench_overlap rows parsed to zero cases "
-                               "(row naming / gate regex drift?)")
-        det_keys = ("depth", "stages", "edges", "concurrent_pairs",
-                    "overlapped_stages", "bitexact")
-        for case, rec in fresh_ov.items():
-            prev = old_ov.get(case)
-            if not prev:
-                continue
-            for key in det_keys:
-                if key not in prev:
-                    continue
-                if key not in rec:
-                    regressions.append(
-                        f"overlap/{case} {key}: recorded counter missing "
-                        "from the fresh run")
-                elif rec[key] != prev[key]:
-                    regressions.append(
-                        f"overlap/{case} {key}: {prev[key]} -> {rec[key]} "
-                        "(plan-derived schedule counters must replay "
-                        "exactly)")
     if regressions:
         raise SystemExit("bench regression gate (>"
                          f"{int(threshold * 100)}% vs {BENCH_JSON.name}): "
@@ -588,12 +535,7 @@ def write_bench_json(results: dict) -> None:
         # merge per case so a BENCH_SMOKE run (one case, two capacities)
         # never shrinks the committed capacity sweep
         data.setdefault("residency", {}).update(parse_residency(rd))
-    ov = results.get("bench_overlap")
-    if ov:
-        # merge per case so a BENCH_SMOKE run (one case per overlap source)
-        # never shrinks the committed overlap sweep
-        data.setdefault("overlap", {}).update(parse_overlap(ov))
-    if sb or nf or se or pt or ly or sv or rz or rd or ov:
+    if sb or nf or se or pt or ly or sv or rz or rd:
         BENCH_JSON.write_text(json.dumps(data, indent=2, sort_keys=True) + "\n")
         print(f"# wrote {BENCH_JSON.name}", flush=True)
 

@@ -413,51 +413,6 @@ def _residency(data: dict) -> list:
     return out
 
 
-def _overlap(data: dict) -> list:
-    ov = data.get("overlap")
-    if not ov:
-        return []
-    out = [
-        "",
-        "## Async stage-graph pipelining: critical path vs serial sum "
-        "(`ScheduleSpec`)",
-        "",
-        "Beyond-paper: the serial FP→NA→SA chain relaxed to the "
-        "plan-derived dependency DAG (`StageGraphExecutor.schedule_edges`) "
-        "— the partitioned halo exchange runs concurrently with NA over "
-        "owned rows, and the bucketed/instance NA layouts dispatch one NA "
-        "stage per metapath with a single join at SA "
-        "(`benchmarks/bench_overlap.py`).  Every overlapped mode is "
-        "**bit-exact** vs the serial schedule; the DAG counters and the "
-        "bit-exactness flag are gated by `benchmarks/run.py --check` at "
-        "exact equality, the serial-sum / critical-path walls are recorded "
-        "but never gated.",
-        "",
-        "| model/dataset/case | stages | edges | concurrent pairs | "
-        "bit-exact | serial sum | critical path | saved |",
-        "| --- | --- | --- | --- | --- | --- | --- | --- |",
-    ]
-    for case in sorted(ov):
-        r = ov[case]
-        ser, crit, saved = (
-            (_us(r[k]) if k in r else "—")
-            for k in ("serial_sum_us", "critical_path_us",
-                      "overlap_saved_us"))
-        out.append(
-            f"| {case} | {r.get('stages', 0)} | {r.get('edges', 0)} | "
-            f"{r.get('concurrent_pairs', 0)} | "
-            f"{'yes' if r.get('bitexact') else 'NO'} | "
-            f"{ser} | {crit} | {saved} |")
-    out += [
-        "",
-        "The saving is the halo exchange / sibling-metapath wall hidden "
-        "behind the longest concurrent stage; per-stage *exposure* "
-        "(`core/characterize.py::overlap_accounting`) attributes the "
-        "critical path stage-by-stage in the bench rows.",
-    ]
-    return out
-
-
 def render(data: dict) -> str:
     lines = [HEADER]
     lines += _stage_breakdown(data)
@@ -469,18 +424,16 @@ def render(data: dict) -> str:
     lines += _serving(data)
     lines += _resilience(data)
     lines += _residency(data)
-    lines += _overlap(data)
     lines += [
         "",
         "## Regenerating",
         "",
         "```bash",
         "# refresh the snapshot (stage breakdown + NA/SA fusion + partition",
-        "# + depth sweep + request-path serving + chaos counters + residency",
-        "# + async stage-graph overlap)",
+        "# + depth sweep + request-path serving + chaos counters + residency)",
         "PYTHONPATH=src:. python benchmarks/run.py bench_stage_breakdown \\",
         "    bench_na_fused bench_sa_epilogue bench_partition bench_layers \\",
-        "    bench_serving bench_resilience bench_residency bench_overlap",
+        "    bench_serving bench_resilience bench_residency",
         "# re-render this page",
         "python scripts/gen_characterization.py",
         "```",

@@ -203,15 +203,6 @@ class HGNNConfig:
     # runs against a live pinned cache. 0 = no cache (every gather re-reads
     # HBM). Bit-exact by construction: cache rows are bitwise row copies.
     cache_rows: int = 0
-    # Async stage-graph schedule (core/plan.py ScheduleSpec): >= 1 runs the
-    # executor's dependency DAG with that many stages in flight — the halo
-    # exchange overlaps NA over owned rows, per-metapath NA stages dispatch
-    # concurrently (merge at SA), and serving prefetches the next slot
-    # batch while the device computes. 1 is the serial-degenerate schedule
-    # (every stage blocked — the parity baseline); 0 keeps the strict
-    # serial stage loop with no schedule at all. Bit-exact either way:
-    # overlap changes when stages run, never what they compute.
-    overlap: int = 0
     seed: int = 0
 
     def __post_init__(self):

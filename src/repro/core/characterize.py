@@ -501,49 +501,6 @@ def resilience_record(stats: Dict) -> Dict:
 
 
 # ---------------------------------------------------------------------------
-# async stage-graph overlap accounting
-# ---------------------------------------------------------------------------
-
-
-def overlap_accounting(edges: Dict[str, Tuple[str, ...]],
-                       walls_us: Dict[str, float]) -> Dict:
-    """Critical-path accounting over the plan-derived stage DAG.
-
-    ``edges`` is :meth:`repro.core.pipeline.StageGraphExecutor.
-    schedule_edges` (stage → its dependencies, topological order);
-    ``walls_us`` the measured per-stage walls.  The *serial sum* is the
-    blocking schedule's lower bound (every stage waits for the previous
-    one); the *critical path* is the overlapped schedule's — the longest
-    dependency chain when independent stages run concurrently.  Their gap
-    is the overlap saving; per-stage **exposure** is how much of the
-    critical path a stage is actually responsible for (critical path minus
-    the critical path with that stage's wall zeroed) — a fully-hidden
-    stage (e.g. a halo exchange shorter than the owned-rows NA it overlaps)
-    exposes ~0 even with a large wall.
-    """
-    finish: Dict[str, float] = {}
-    for n in edges:  # topological by construction
-        finish[n] = (max((finish[d] for d in edges[n]), default=0.0)
-                     + walls_us.get(n, 0.0))
-    crit = max(finish.values(), default=0.0)
-
-    def _crit_without(skip: str) -> float:
-        f: Dict[str, float] = {}
-        for n in edges:
-            w = 0.0 if n == skip else walls_us.get(n, 0.0)
-            f[n] = max((f[d] for d in edges[n]), default=0.0) + w
-        return max(f.values(), default=0.0)
-
-    serial = float(sum(walls_us.get(n, 0.0) for n in edges))
-    return {
-        "serial_sum_us": serial,
-        "critical_path_us": float(crit),
-        "overlap_saved_us": float(serial - crit),
-        "exposure_us": {n: float(crit - _crit_without(n)) for n in edges},
-    }
-
-
-# ---------------------------------------------------------------------------
 # model-level analytics + roofline
 # ---------------------------------------------------------------------------
 

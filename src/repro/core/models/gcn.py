@@ -21,7 +21,7 @@ from repro.core import stages
 from repro.core.hgraph import HeteroGraph
 from repro.core.pipeline import PlannedModel
 from repro.core.plan import (FPSpec, HeadSpec, LayerPlan, NASpec,
-                             ResidencySpec, SampleSpec, SASpec, ScheduleSpec,
+                             ResidencySpec, SampleSpec, SASpec,
                              StagePlan, default_sample_ladder)
 from repro.data.synthetic import DATASET_TARGET
 
@@ -61,11 +61,6 @@ class GCN(PlannedModel):
                 for l in range(self.cfg.layers)),
             head=HeadSpec(kind="linear", param="w2"),
             sample=sample,
-            # gcn's single homogeneous NA stage has no intra-layer
-            # concurrency; the schedule still drives layer-to-layer async
-            # dispatch and the serving prefetch thread
-            schedule=(ScheduleSpec(depth=cfg.overlap)
-                      if cfg.overlap >= 1 else None),
         )
 
     def prepare(self, hg: HeteroGraph) -> Dict:

@@ -29,7 +29,7 @@ from repro.core.pipeline import PlannedModel
 from repro.core.plan import (BUCKETED_BATCH_SPECS, PARTITION_BATCH_SPECS,
                              STACKED_BATCH_SPECS, FPSpec, HeadSpec, LayerPlan,
                              NASpec, PartitionSpec, ResidencySpec, SampleSpec,
-                             SASpec, ScheduleSpec, StagePlan,
+                             SASpec, StagePlan,
                              default_sample_ladder)
 from repro.data.synthetic import DATASET_METAPATHS, DATASET_TARGET
 
@@ -93,8 +93,6 @@ class HAN(PlannedModel):
                          else STACKED_BATCH_SPECS),
             partition=part,
             sample=sample,
-            schedule=(ScheduleSpec(depth=cfg.overlap)
-                      if cfg.overlap >= 1 else None),
         )
 
     # ---------------- Stage 1: Subgraph Build (host) ----------------

@@ -37,7 +37,7 @@ from repro.core.plan import (INSTANCE_BATCH_SPECS,
                              INSTANCE_BUCKET_BATCH_SPECS,
                              PARTITION_BATCH_SPECS, FPSpec, HeadSpec,
                              LayerPlan, NASpec, PartitionSpec, ResidencySpec,
-                             SampleSpec, SASpec, ScheduleSpec, StagePlan,
+                             SampleSpec, SASpec, StagePlan,
                              default_sample_ladder)
 from repro.data.synthetic import DATASET_METAPATHS, DATASET_TARGET
 from repro.serve.spans import span
@@ -99,8 +99,6 @@ class MAGNN(PlannedModel):
                          else INSTANCE_BATCH_SPECS),
             partition=part,
             sample=sample,
-            schedule=(ScheduleSpec(depth=cfg.overlap)
-                      if cfg.overlap >= 1 else None),
         )
 
     # ---------------- Stage 1: Subgraph Build (host, metapath instances) ----

@@ -26,7 +26,7 @@ from repro.core.pipeline import PlannedModel
 from repro.core.plan import (PARTITION_BATCH_SPECS, RELATION_BATCH_SPECS,
                              FPSpec, HeadSpec, LayerPlan, NASpec,
                              PartitionSpec, ResidencySpec, SampleSpec, SASpec,
-                             ScheduleSpec, StagePlan, default_sample_ladder)
+                             StagePlan, default_sample_ladder)
 from repro.data.synthetic import DATASET_TARGET
 
 
@@ -83,8 +83,6 @@ class RGCN(PlannedModel):
                          else RELATION_BATCH_SPECS),
             partition=part,
             sample=sample,
-            schedule=(ScheduleSpec(depth=cfg.overlap)
-                      if cfg.overlap >= 1 else None),
         )
 
     # ---------------- Stage 1: Relation Walk (host) ----------------

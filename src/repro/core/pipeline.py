@@ -69,12 +69,6 @@ class StageGraphExecutor:
     def __init__(self, plan: StagePlan, cfg):
         self.plan = plan
         self.cfg = cfg
-        # per-stage jit cache for the async schedule driver: one traced
-        # callable per stage name, reused across forward_overlapped calls
-        # (shapes key jax.jit's own cache below it)
-        self._ov_jit: Dict = {}
-        # last forward_overlapped dispatch trace (tests / accounting)
-        self.last_dispatch: Dict = {}
 
     # ------------------------------------------------------------------
     # params
@@ -278,18 +272,17 @@ class StageGraphExecutor:
     # ------------------------------------------------------------------
     # partitioned flow: the halo feature exchange (the new explicit stage)
     # ------------------------------------------------------------------
-    def halo_exchange(self, batch: Dict, h_own: Dict) -> Dict:
-        """Exchange-only half of :meth:`gather_halo`: fetch each type's
-        halo rows from the other partitions' owned tables — WITHOUT
-        appending them to the local pool.  The async schedule dispatches
-        this concurrently with NA's owned-rows pre-gather (both depend
-        only on FP); the serial path concatenates right below."""
+    def gather_halo(self, batch: Dict, h_own: Dict):
+        """Fetch each type's halo rows from the other partitions' owned
+        tables and append them: local source table = concat(own, halo).
+        The one communication step of the partitioned flow (shard_map
+        all-gather on a dividing mesh; see ``repro.dist.partition``)."""
         from repro.dist.partition import gather_halo as _gather
 
         part = batch["part"]
         mode = self.plan.partition.halo
         res = batch.get("residency")
-        out: Dict = {}
+        halos: Dict = {}
         for t, h in h_own.items():
             halo = _gather(h, part["halo_src"][t], mode=mode)
             if res is not None and t in res.get("halo_slot", {}):
@@ -304,15 +297,7 @@ class StageGraphExecutor:
                 sel = jnp.take(cache, jnp.clip(slot, 0), axis=0)
                 cond = (slot >= 0).reshape(slot.shape + (1,) * len(tail))
                 halo = jnp.where(cond, sel, halo)
-            out[t] = halo
-        return out
-
-    def gather_halo(self, batch: Dict, h_own: Dict):
-        """Fetch each type's halo rows from the other partitions' owned
-        tables and append them: local source table = concat(own, halo).
-        The one communication step of the partitioned flow (shard_map
-        all-gather on a dividing mesh; see ``repro.dist.partition``)."""
-        halos = self.halo_exchange(batch, h_own)
+            halos[t] = halo
         return {t: jnp.concatenate([h, halos[t]], axis=1)
                 for t, h in h_own.items()}
 
@@ -457,8 +442,7 @@ class StageGraphExecutor:
 
     def _na_instance_one(self, params: Dict, batch: Dict,
                          h: Dict[str, jax.Array], i_path: int) -> jax.Array:
-        """One metapath's instance-attention NA — the serial loop body and
-        the async schedule's per-metapath stage share it verbatim.
+        """One metapath's instance-attention NA.
 
         The ``instances`` layout is one ``[N, I, L]`` table; ``bucketed``
         bins the target rows by instance count (``batch["buckets"]``,
@@ -531,31 +515,6 @@ class StageGraphExecutor:
         return [self._na_instance_one(params, batch, h, i)
                 for i in range(len(self.plan.metapaths))]
 
-    def _na_metapath(self, params: Dict, batch: Dict, h, i: int):
-        """One metapath's NA as its own schedulable stage (async schedule,
-        single-device): the bucketed / csr GAT loop body or one MAGNN
-        instance-attention round.  The only delta vs the serial loop is
-        *where* the activation applies — per-metapath here vs post-stack
-        there — which is elementwise, so SA's re-stack is bitwise equal."""
-        plan = self.plan
-        act = _ACT[plan.na.activation]
-        if plan.na.kind == "instance":
-            return self._na_instance_one(params, batch, h, i)
-        pool = self._res_pool(batch, plan.target, h)
-        if plan.na.layout == "csr":
-            seg, idx = batch["edges"][i]
-            z = stages.gat_aggregate_csr(params["gat"][i], h, pool, seg, idx,
-                                         h.shape[0])
-            return act(z).reshape(z.shape[0], -1)  # [N, D]
-        agg_fn = None
-        if plan.na.use_pallas:
-            kops = _kops()
-            agg_fn = lambda p, hd, hs, nn, mm: kops.gat_aggregate(
-                p, hd, hs, nn, mm, use_pallas=True)
-        z = stages.gat_aggregate_bucketed(params["gat"][i], h, pool,
-                                          batch["buckets"][i], agg_fn=agg_fn)
-        return act(z).reshape(z.shape[0], -1)  # [N, D]
-
     def _na_partitioned(self, params: Dict, batch: Dict, h_loc: Dict):
         """NA over partition-local shards: destinations are the owned rows,
         sources the concat(own, halo) local tables built by ``gather_halo``.
@@ -618,123 +577,6 @@ class StageGraphExecutor:
             return outs
         raise ValueError(
             f"no partitioned NA path for kind {plan.na.kind!r}")
-
-    # ------------------------------------------------------------------
-    # partitioned flow, async schedule: the own/halo NA split.
-    #
-    # Serial partitioned NA gathers from concat(own, halo) — it cannot
-    # start until the exchange lands.  But a gather is a pure row
-    # selection, so it splits at the *gather*, never at a float
-    # reduction: the owned-side rows pre-gather against the owned
-    # table alone while the exchange is still in flight, and the merge
-    # where-selects the halo side in afterwards (stages.gather_own /
-    # gather_merge — bitwise equal to the concat-then-gather).  All the
-    # attention / mean arithmetic runs once, in the merge, on the merged
-    # operands — identical values in identical reduction order.
-    # ------------------------------------------------------------------
-    def _na_partitioned_own(self, params: Dict, batch: Dict, h_own: Dict):
-        """Owned-rows pre-gather pass: everything partitioned NA can do
-        from FP's output alone (depends only on FP — runs concurrently
-        with ``halo_exchange``).  Returns the pre-gathered operand pytree
-        :meth:`_na_partitioned_merge` consumes."""
-        plan = self.plan
-        part = batch["part"]
-        t = plan.target
-        if plan.na.kind == "gat":
-            def one_part(hs_k, nbr_k):  # hs_k [n, D], nbr_k [P, n, Kd]
-                return jax.vmap(lambda nn: stages.gather_own(hs_k, nn))(nbr_k)
-
-            # flat rows, as gat_aggregate_padded gathers them
-            return jax.vmap(one_part)(h_own[t], part["nbr"])  # [K,P,n,Kd,D]
-        if plan.na.kind == "mean":
-            out: Dict = {}
-            for key in sorted(part["rels"]):
-                nbr, _ = part["rels"][key]
-                out["|".join(key)] = jax.vmap(stages.gather_own)(
-                    h_own[key[0]], nbr)  # [K, n_d, Kd, D]
-            return out
-        if plan.na.kind == "instance":
-            outs: List = []
-            for (nodes, _), types in zip(part["instances"], plan.metapaths):
-                pools = stages.position_tables(h_own, params["rel"], types)
-                outs.append([
-                    jax.vmap(stages.gather_own)(pools[j], nodes[:, :, :, j])
-                    for j in range(nodes.shape[3])
-                ])  # per position, rotated: [K, n, I, D]
-            return outs
-        raise ValueError(
-            f"no partitioned NA split for kind {plan.na.kind!r}")
-
-    def _na_partitioned_merge(self, params: Dict, batch: Dict, h_own: Dict,
-                              halos: Dict, pre):
-        """Merge pass: where-select the exchanged halo rows into the
-        pre-gathered owned operands, then run the untouched aggregation
-        math.  Output bitwise equals ``_na_partitioned(params, batch,
-        gather_halo(batch, h_own))``."""
-        plan, cfg = self.plan, self.cfg
-        part = batch["part"]
-        t = plan.target
-        act = _ACT[plan.na.activation]
-        H = cfg.n_heads
-        if plan.na.kind == "gat":
-            n_own = part["feats"][t].shape[1]
-            heads = lambda x: x.reshape(x.shape[0], x.shape[1], H, -1)
-            hd = heads(h_own[t])  # [K, n, H, Dh] owned rows ARE the dsts
-
-            def one_part(hd_k, hh_k, nbr_k, mask_k, hno_k):  # hh_k [h_max, D]
-                def one_path(pp, nn, mm, hno):
-                    hn = stages.gather_merge(hno, hh_k, nn, n_own)
-                    return stages.gat_aggregate_padded(
-                        pp, hd_k, None, None, mm, hn=hn)
-
-                return jax.vmap(one_path)(params["gat"], nbr_k, mask_k, hno_k)
-
-            z = jax.vmap(one_part)(hd, halos[t], part["nbr"], part["mask"],
-                                   pre)
-            z = act(z)  # [K, P, n, H, Dh]
-            z = z.reshape(z.shape[0], z.shape[1], z.shape[2], -1)
-            return stages.shard(z, BATCH, None, None, None)  # [K, P, n, D]
-        if plan.na.kind == "mean":
-            if plan.n_layers > 1:
-                out: Dict = {"__h__": {ty: h_own[ty]
-                                       for ty in part["feats"]}}
-            else:
-                out = {"__h__": h_own[t]}
-            for key in sorted(part["rels"]):
-                s = key[0]
-                n_own_s = part["feats"][s].shape[1]
-                nbr, mask = part["rels"][key]
-                hn = jax.vmap(
-                    lambda ho, hl, nn: stages.gather_merge(
-                        ho, hl, nn, n_own_s)
-                )(pre["|".join(key)], halos[s], nbr)
-                agg = jax.vmap(
-                    lambda nn, mm, hh: stages.mean_aggregate_padded(
-                        None, nn, mm, hn=hh)
-                )(nbr, mask, hn)  # [K, n_d, D]
-                out["|".join(key)] = agg @ params["w_rel"][key]
-            return out
-        if plan.na.kind == "instance":
-            h_tgt = h_own[t]  # [K, n, D]
-            outs: List[jax.Array] = []
-            for p_i, (nodes, mask), types, pre_i in zip(params["att"],
-                                                        part["instances"],
-                                                        plan.metapaths, pre):
-                k_, n, _, l = nodes.shape
-                pools = stages.position_tables(halos, params["rel"], types)
-                enc = stages.rotation_encoder([
-                    jax.vmap(
-                        lambda ho, hl, nn, ty=types[j]: stages.gather_merge(
-                            ho, hl, nn, part["feats"][ty].shape[1])
-                    )(pre_i[j], pools[j], nodes[:, :, :, j])
-                    for j in range(l)
-                ])  # from [K, n, I, D] per position
-                z = jax.vmap(stages.instance_aggregate,
-                             in_axes=(None, 0, 0, 0))(p_i, h_tgt, enc, mask)
-                outs.append(act(z).reshape(k_, n, -1))  # [K, n, H * D]
-            return outs
-        raise ValueError(
-            f"no partitioned NA split for kind {plan.na.kind!r}")
 
     # ------------------------------------------------------------------
     # Stage 4: Semantic Aggregation
@@ -830,299 +672,75 @@ class StageGraphExecutor:
             return z[plan.head.target] @ w
         return z @ w
 
-    def forward(self, params: Dict, batch: Dict) -> jax.Array:
-        """The L-layer loop: per-type feature tables are the carried state;
-        layer 0 reads the prepared batch, hidden layers the previous
-        handoff.  The partitioned flow re-exchanges the *updated* halo
-        features every layer over the graph-invariant halo maps.
+    def _walk(self, params: Dict, batch: Dict, emit):
+        """The L-layer loop, written once: per-type feature tables are the
+        carried state; layer 0's FP reads the prepared batch, hidden layers
+        the previous handoff.  The partitioned flow re-exchanges the
+        *updated* halo features every layer over the graph-invariant halo
+        maps.
 
-        Each stage runs under a ``jax.named_scope`` of its
-        :meth:`stage_fns` name (``FP``, ``gather_halo``, ``NA``, ``SA``,
-        ``head``; ``L{i}.``-prefixed when L > 1), so the compiled ops name
-        their stage; the layer handoff is in no stage."""
+        Every stage runs as ``emit(name, fn, args)``: ``name`` is ``FP``,
+        ``gather_halo``, ``NA``, ``SA`` or ``head``, ``L{i}.``-prefixed
+        (1-based) when L > 1; ``fn(*args)`` computes the stage.  The
+        closures capture ``batch``, and the one ``gather_halo`` callable is
+        shared by every layer.  The layer handoff is in no stage.  Matmuls
+        run at ``MATMUL_PRECISION``."""
         plan = self.plan
+        gh = lambda hh: self.gather_halo(batch, hh)
         state = out = None
         with jax.default_matmul_precision(MATMUL_PRECISION):
             for l, lp in enumerate(plan.layers):
                 pre = f"L{l + 1}." if plan.n_layers > 1 else ""
-                p_l = self._layer_params(params, l)
-                with jax.named_scope(pre + "FP"):
-                    h = (self.fp(params, batch) if l == 0
-                         else self._fp_hidden(lp, p_l, state))
+                if l == 0:
+                    h = emit(pre + "FP", lambda p: self.fp(p, batch),
+                             (params,))
+                else:
+                    h = emit(pre + "FP",
+                             lambda p, s, lp=lp, l=l: self._fp_hidden(
+                                 lp, self._layer_params(p, l), s),
+                             (params, state))
                 if plan.partition is not None:
-                    with jax.named_scope(pre + "gather_halo"):
-                        h = self.gather_halo(batch, h)
-                with jax.named_scope(pre + "NA"):
-                    z = self.na(p_l, batch, h)
-                with jax.named_scope(pre + "SA"):
-                    out = self.sa(p_l, batch, z)
+                    h = emit(pre + "gather_halo", gh, (h,))
+                z = emit(pre + "NA", lambda p, hh, l=l: self.na(
+                    self._layer_params(p, l), batch, hh), (params, h))
+                out = emit(pre + "SA", lambda p, zz, l=l: self.sa(
+                    self._layer_params(p, l), batch, zz), (params, z))
                 if l + 1 < plan.n_layers:
                     state = self._handoff(lp, batch, h, out)
-            with jax.named_scope("head"):
-                return self.head(params, out, batch)
+            return emit("head", lambda p, oo: self.head(p, oo, batch),
+                        (params, out))
 
-    # ------------------------------------------------------------------
-    # the async stage-graph schedule (plan.schedule)
-    # ------------------------------------------------------------------
-    def _split_halo(self) -> bool:
-        """Does the schedule split partitioned NA into own/halo passes?"""
-        s = self.plan.schedule
-        return (s is not None and s.overlap_halo
-                and self.plan.partition is not None)
+    def forward(self, params: Dict, batch: Dict) -> jax.Array:
+        """:meth:`_walk` with every stage inline under a
+        ``jax.named_scope`` of its name, so the compiled ops name their
+        stage."""
+        def emit(name, fn, args):
+            with jax.named_scope(name):
+                return fn(*args)
 
-    def _split_metapaths(self) -> bool:
-        """Does the schedule dispatch per-metapath NA stages?  Only where
-        the serial path already loops metapaths (bucketed / csr GAT,
-        MAGNN instances) — the stacked layout is ONE launch by design,
-        and a single metapath has nothing to overlap."""
-        plan, s = self.plan, self.plan.schedule
-        return (s is not None and s.overlap_metapaths
-                and plan.partition is None
-                and len(plan.metapaths) > 1
-                and ((plan.na.kind == "gat"
-                      and plan.na.layout in ("csr", "bucketed"))
-                     or plan.na.kind == "instance"))
-
-    def _sa_entry(self, p_l: Dict, batch: Dict, z):
-        """SA entry for the schedule driver: per-metapath NA stages hand
-        SA a list; stacked-SA plans re-stack it here.  Activation already
-        applied per metapath (elementwise) — stack-after-act is bitwise
-        equal to the serial act-after-stack."""
-        if self._split_metapaths() and self.plan.sa.stacked:
-            z = jnp.stack(z)  # [P, N, D]
-        return self.sa(p_l, batch, z)
-
-    def schedule_edges(self) -> Dict[str, Tuple[str, ...]]:
-        """The plan-derived dependency-edge table: stage name → the stages
-        it must wait for, in topological order.  Purely declarative — the
-        driver, the accounting, and the tests all read the same DAG.
-        Nodes match the schedule's dispatch granularity: the partitioned
-        split runs ``gather_halo`` (exchange only) and ``NA.own``
-        concurrently, merging in ``NA``; the metapath split fans ``FP``
-        out into ``NA.p{i}`` stages that join at ``SA``."""
-        plan = self.plan
-        edges: Dict[str, Tuple[str, ...]] = {}
-        prev = None
-        for l in range(plan.n_layers):
-            pre = f"L{l + 1}." if plan.n_layers > 1 else ""
-            edges[pre + "FP"] = (prev,) if prev else ()
-            if plan.partition is not None:
-                edges[pre + "gather_halo"] = (pre + "FP",)
-                if self._split_halo():
-                    edges[pre + "NA.own"] = (pre + "FP",)
-                    edges[pre + "NA"] = (pre + "NA.own", pre + "gather_halo")
-                else:
-                    edges[pre + "NA"] = (pre + "gather_halo",)
-                sa_deps: Tuple[str, ...] = (pre + "NA",)
-            elif self._split_metapaths():
-                names = [pre + f"NA.p{i}"
-                         for i in range(len(plan.metapaths))]
-                for nm in names:
-                    edges[nm] = (pre + "FP",)
-                sa_deps = tuple(names)
-            else:
-                edges[pre + "NA"] = (pre + "FP",)
-                sa_deps = (pre + "NA",)
-            edges[pre + "SA"] = sa_deps
-            prev = pre + "SA"
-        edges["head"] = (prev,)
-        return edges
-
-    def overlap_record(self) -> Dict:
-        """Deterministic schedule counters (no walls): DAG size and the
-        path-independent stage pairs — the concurrency the schedule can
-        exploit.  Pinned by CI greps and gated at exact equality by the
-        bench; the measured critical-path/exposure accounting lives in
-        ``characterize.overlap_accounting``."""
-        edges = self.schedule_edges()
-        names = list(edges)
-        anc: Dict[str, set] = {}
-        for n in names:  # topological by construction
-            a = set()
-            for d in edges[n]:
-                a.add(d)
-                a |= anc[d]
-            anc[n] = a
-        pairs = [(a, b) for i, a in enumerate(names) for b in names[i + 1:]
-                 if a not in anc[b] and b not in anc[a]]
-        sched = self.plan.schedule
-        return {
-            "depth": sched.depth if sched is not None else 1,
-            "stages": len(names),
-            "edges": sum(len(d) for d in edges.values()),
-            "concurrent_pairs": len(pairs),
-            "overlapped_stages": len({s for p in pairs for s in p}),
-            "pairs": [f"{a}|{b}" for a, b in pairs],
-        }
-
-    def _ovjit(self, key: str, fn):
-        f = self._ov_jit.get(key)
-        if f is None:
-            f = self._ov_jit[key] = jax.jit(fn)
-        return f
-
-    def _walk_schedule(self, params: Dict, batch: Dict, emit):
-        """Walk the stage DAG in topological order, dispatching each stage
-        through ``emit(name, key, fn, args) -> value``.  ``name`` is the
-        per-layer stage name (matches :meth:`schedule_edges`); ``key`` the
-        jit-cache identity (layer-indexed — stage shapes repeat across
-        calls, not across layers with different param trees).  Both the
-        async driver and the characterization hook walk this one graph."""
-        plan = self.plan
-        n_l = plan.n_layers
-        state = out = None
-        for l, lp in enumerate(plan.layers):
-            pre = f"L{l + 1}." if n_l > 1 else ""
-            if l == 0:
-                h = emit(pre + "FP", "FP0",
-                         lambda p, b: self.fp(p, b), (params, batch))
-            else:
-                h = emit(pre + "FP", f"FP{l}",
-                         lambda p, s, lp=lp, l=l: self._fp_hidden(
-                             lp, self._layer_params(p, l), s),
-                         (params, state))
-            if plan.partition is not None:
-                if self._split_halo():
-                    # the exchange and the owned-rows pre-gather both
-                    # depend only on FP — the window dispatches them
-                    # back-to-back and they run concurrently
-                    halos = emit(pre + "gather_halo", "halo_exchange",
-                                 lambda b, hh: self.halo_exchange(b, hh),
-                                 (batch, h))
-                    pre_g = emit(pre + "NA.own", f"NA.own{l}",
-                                 lambda p, b, hh, l=l:
-                                 self._na_partitioned_own(
-                                     self._layer_params(p, l), b, hh),
-                                 (params, batch, h))
-                    z = emit(pre + "NA", f"NA.merge{l}",
-                             lambda p, b, hh, ha, pg, l=l:
-                             self._na_partitioned_merge(
-                                 self._layer_params(p, l), b, hh, ha, pg),
-                             (params, batch, h, halos, pre_g))
-                else:
-                    h = emit(pre + "gather_halo", "gather_halo",
-                             lambda b, hh: self.gather_halo(b, hh),
-                             (batch, h))
-                    z = emit(pre + "NA", f"NA{l}",
-                             lambda p, b, hh, l=l: self.na(
-                                 self._layer_params(p, l), b, hh),
-                             (params, batch, h))
-            elif self._split_metapaths():
-                z = [emit(pre + f"NA.p{i}", f"NA.p{l}.{i}",
-                          lambda p, b, hh, l=l, i=i: self._na_metapath(
-                              self._layer_params(p, l), b, hh, i),
-                          (params, batch, h))
-                     for i in range(len(plan.metapaths))]
-            else:
-                z = emit(pre + "NA", f"NA{l}",
-                         lambda p, b, hh, l=l: self.na(
-                             self._layer_params(p, l), b, hh),
-                         (params, batch, h))
-            out = emit(pre + "SA", f"SA{l}",
-                       lambda p, b, zz, l=l: self._sa_entry(
-                           self._layer_params(p, l), b, zz),
-                       (params, batch, z))
-            if l + 1 < n_l:
-                # host-level repackaging (slices are identities on the
-                # own-only tables) — not a schedulable stage
-                state = self._handoff(lp, batch, h, out)
-        return emit("head", "head",
-                    lambda p, b, oo: self.head(p, oo, b),
-                    (params, batch, out))
-
-    def forward_overlapped(self, params: Dict, batch: Dict) -> jax.Array:
-        """``forward``'s layer loop re-expressed over the plan-derived
-        stage DAG: each stage is its own jitted call; the host races ahead
-        issuing dependents and blocks only when more than
-        ``plan.schedule.depth`` stage results are in flight
-        (``kernels.streaming.InflightWindow`` — the DMA double-buffer
-        discipline at stage granularity), so JAX's async dispatch runs
-        independent stages' device work concurrently.  Bit-exact vs the
-        serial schedule: the split stages are pure row selections /
-        elementwise rearrangements; depth=1 degrades to fully blocking
-        dispatch.  Not itself jit-able (it *is* the dispatcher); the
-        per-stage jits are cached on the executor, so repeated calls
-        re-trace nothing."""
-        from repro.kernels.streaming import InflightWindow
-
-        sched = self.plan.schedule
-        win = InflightWindow(sched.depth if sched is not None else 1)
-
-        def emit(name, key, fn, args):
-            return win.admit(name, self._ovjit(key, fn)(*args))
-
-        with jax.default_matmul_precision(MATMUL_PRECISION):
-            out = self._walk_schedule(params, batch, emit)
-        win.drain()
-        self.last_dispatch = {
-            "dispatched": list(win.admitted),
-            "max_inflight": win.max_inflight,
-            "depth": win.depth,
-        }
-        return out
+        return self._walk(params, batch, emit)
 
     # ------------------------------------------------------------------
     # per-stage characterization hooks
     # ------------------------------------------------------------------
     def stage_fns(self, params: Dict, batch: Dict) -> Dict[str, Tuple]:
-        """Jitted per-stage callables chained on concrete intermediates —
-        the separate jit per stage mirrors DGL's separate kernel launches
-        and exposes the NA→SA barrier (paper Fig. 5c).
-
-        Single-layer plans keep the historical unprefixed stage names
-        (``FP``/``gather_halo``/``NA``/``SA``/``head``); an L-layer stack
-        prefixes every per-layer stage with ``L{i}.`` (1-based), so the
-        characterization handbook can show depth scaling per layer."""
-        plan = self.plan
-        n_l = plan.n_layers
+        """:meth:`_walk` with every stage its own jit, run on the concrete
+        output of the stage before: stage name -> ``(jitted fn, args)``.
+        The separate jit per stage mirrors DGL's separate kernel launches
+        and exposes the NA→SA barrier (paper Fig. 5c).  One jit serves
+        every layer's ``gather_halo`` (same computation on same-shaped
+        tables)."""
         fns: Dict[str, Tuple] = {}
-        state = out = None
-        # one jitted exchange shared by every layer (same computation on
-        # same-shaped tables — a per-layer lambda would recompile it L times)
-        gh = (jax.jit(lambda hh: self.gather_halo(batch, hh))
-              if plan.partition is not None else None)
-        for l, lp in enumerate(plan.layers):
-            pre = f"L{l + 1}." if n_l > 1 else ""
-            if l == 0:
-                fp = jax.jit(lambda p: self.fp(p, batch))
-                fp_args: Tuple = (params,)
-            else:
-                fp = jax.jit(lambda p, s, lp=lp, l=l: self._fp_hidden(
-                    lp, self._layer_params(p, l), s))
-                fp_args = (params, state)
-            h = fp(*fp_args)
-            fns[pre + "FP"] = (fp, fp_args)
-            if gh is not None:
-                fns[pre + "gather_halo"] = (gh, (h,))
-                h = gh(h)
-            na = jax.jit(lambda p, hh, l=l: self.na(
-                self._layer_params(p, l), batch, hh))
-            z = na(params, h)
-            fns[pre + "NA"] = (na, (params, h))
-            sa = jax.jit(lambda p, zz, l=l: self.sa(
-                self._layer_params(p, l), batch, zz))
-            out = sa(params, z)
-            fns[pre + "SA"] = (sa, (params, z))
-            if l + 1 < n_l:
-                state = self._handoff(lp, batch, h, out)
-        head = jax.jit(lambda p, oo: self.head(p, oo, batch))
-        fns["head"] = (head, (params, out))
-        return fns
+        jits: Dict = {}
 
-    def overlap_stage_fns(self, params: Dict, batch: Dict) -> Dict[str, Tuple]:
-        """Overlap-granular analogue of :meth:`stage_fns`: one jitted
-        callable per node of :meth:`schedule_edges`, chained on concrete
-        intermediates.  The benches time each stage's wall and feed the
-        DAG + walls to ``characterize.overlap_accounting`` (critical-path
-        vs serial-sum, per-stage exposure)."""
-        fns: Dict[str, Tuple] = {}
-
-        def emit(name, key, fn, args):
-            f = jax.jit(fn)
+        def emit(name, fn, args):
+            f = jits.get(fn)
+            if f is None:
+                f = jits[fn] = jax.jit(fn)
             fns[name] = (f, args)
             return f(*args)
 
-        self._walk_schedule(params, batch, emit)
+        self._walk(params, batch, emit)
         return fns
 
     def stage_records(self, params: Dict, batch: Dict,
@@ -1147,7 +765,10 @@ class StageGraphExecutor:
         if sample_meta is not None:
             recs["SAMPLE"] = sample_meta
         for name, (fn, args) in fns.items():
-            rep = analyze_hlo_text(fn.lower(*args).compile().as_text())
+            # lowered at the precision the stage ran at (one trace)
+            with jax.default_matmul_precision(MATMUL_PRECISION):
+                lowered = fn.lower(*args)
+            rep = analyze_hlo_text(lowered.compile().as_text())
             recs[name] = {
                 "flops": rep["total_flops"],
                 "hbm_bytes": rep["total_hbm_bytes"],
@@ -1183,11 +804,6 @@ class StageGraphExecutor:
         out = {"stages": recs, "total": total}
         if rr is not None:
             out["residency"] = rr
-        if self.plan.schedule is not None:
-            # schedule accounting: the DAG's deterministic counters (the
-            # measured critical-path walls ride the overlap bench, not the
-            # HLO records)
-            out["overlap"] = self.overlap_record()
         gh_names = [n for n in fns if n.endswith("gather_halo")]
         if gh_names:
             # the communication stage's paper-facing metrics: exchanged halo
@@ -1272,9 +888,6 @@ class PlannedModel:
 
     def forward(self, params: Dict, batch: Dict) -> jax.Array:
         return self.executor.forward(params, batch)
-
-    def forward_overlapped(self, params: Dict, batch: Dict) -> jax.Array:
-        return self.executor.forward_overlapped(params, batch)
 
     def stage_records(self, params: Dict, batch: Dict, n_chips: int = 1):
         return self.executor.stage_records(params, batch, n_chips=n_chips)

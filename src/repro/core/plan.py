@@ -200,54 +200,6 @@ class ResidencySpec:
     pin_targets: bool = True
 
 
-@dataclass(frozen=True)
-class ScheduleSpec:
-    """Async stage-graph schedule (``StageGraphExecutor.forward_overlapped``).
-
-    The paper characterizes HGNN inference as a chain of stages with
-    sharply different bound-ness (FP compute-bound, NA memory-latency
-    bound, SA reduction-bound) executing back-to-back with hard barriers.
-    A plan that carries a ``ScheduleSpec`` declares that its stage graph
-    may instead run as a dependency DAG: the executor derives the edge
-    table from the plan (:meth:`~repro.core.pipeline.StageGraphExecutor.
-    schedule_edges`) and dispatches independent stages without blocking,
-    keeping at most ``depth`` stages in flight on JAX's async dispatch
-    stream.  Overlap changes *when* stages run, never *what* they compute
-    — every schedule is bit-exact vs the serial ``forward`` loop.
-
-    Three independence sources are exploited (HiHGNN's inter-stage
-    overlap, SiHGNN's semantic-graph stage parallelism):
-
-    ``overlap_halo``       partitioned arm: NA is split into an owned-rows
-                           pre-gather pass that depends only on FP, so the
-                           ``gather_halo`` exchange runs concurrently with
-                           it; a where-select merge (bitwise equal to the
-                           serial concat-then-gather) joins them before
-                           the attention math.
-    ``overlap_metapaths``  bucketed / instance NA: per-metapath stages are
-                           independent until SA's semantic reduction, so
-                           each dispatches as its own stage (single merge
-                           point at SA) — which also overlaps metapath
-                           p+1's gather issue with metapath p's math.
-    ``prefetch``           serving: a host-side thread samples the next
-                           step's slot batch while the device runs the
-                           current jitted forward (``HGNNServeEngine``).
-
-    ``depth`` bounds the in-flight window: 1 degrades to the serial
-    schedule (every stage blocked on dispatch — the parity baseline),
-    2 double-buffers, larger values deepen the pipeline.
-    """
-
-    depth: int = 2  # max stages in flight (1 = serial-degenerate)
-    overlap_halo: bool = True
-    overlap_metapaths: bool = True
-    prefetch: bool = True
-
-    def __post_init__(self):
-        if self.depth < 1:
-            raise ValueError(f"ScheduleSpec.depth must be >= 1: {self.depth}")
-
-
 def default_sample_ladder(
     fanout: int, width: int, hops: int = 1,
     t_rungs: Tuple[int, ...] = (8, 32, 128),
@@ -323,8 +275,6 @@ class StagePlan:
     partition: Optional[PartitionSpec] = None
     # Request-path sampled-minibatch mode (None = full-graph batches only).
     sample: Optional[SampleSpec] = None
-    # Async stage-graph schedule (None = strict serial stage loop).
-    schedule: Optional[ScheduleSpec] = None
 
     def __post_init__(self):
         if not self.layers:

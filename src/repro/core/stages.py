@@ -115,10 +115,9 @@ def head_matrices(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
 def gat_aggregate_padded(
     p: Dict[str, jax.Array],
     h_dst: jax.Array,  # [N, H, Dh] projected features of target nodes
-    h_src: Optional[jax.Array],  # [M, H, Dh] projected neighbor pool
-    nbr: jax.Array,  # [N, K] int32 (may be None when hn is pre-gathered)
+    h_src: jax.Array,  # [M, H, Dh] projected neighbor pool
+    nbr: jax.Array,  # [N, K] int32
     mask: jax.Array,  # [N, K] float
-    hn: Optional[jax.Array] = None,  # [N, K, H*Dh] pre-gathered neighbors
 ) -> jax.Array:
     """GAT neighbor aggregation over a padded subgraph. Returns [N, H, Dh].
 
@@ -127,18 +126,12 @@ def gat_aggregate_padded(
     source scores are one matmul with the block-diagonal ``a_src``, and
     ``alpha`` spreads over each head's lanes by a 0/1 matmul (exact at
     ``highest``), so no ``[H, Dh]`` block is ever laid out per neighbor.
-    ``hn`` lets the async schedule's split stages supply the gather
-    pre-merged (owned rows gathered while the halo exchange was still in
-    flight, where-selected against the halo rows afterwards) — pure row
-    selections, so the math below is bitwise identical to the
-    gather-from-``h_src`` default.
     """
     n, n_heads, head_dim = h_dst.shape
     a_src, spread = head_matrices(p["a_src"])
     hi = jax.lax.Precision.HIGHEST
     e_dst = (h_dst * p["a_dst"]).sum(-1)  # [N, H]   EW
-    if hn is None:
-        hn = h_src.reshape(h_src.shape[0], -1)[nbr]  # [N, K, H*Dh] TB gather
+    hn = h_src.reshape(h_src.shape[0], -1)[nbr]  # [N, K, H*Dh] TB gather
     e_nbr = jnp.dot(hn, a_src, precision=hi)  # [N, K, H] source scores
     e = _leaky_relu(e_dst[:, None, :] + e_nbr)  # [N, K, H]
     e = jnp.where(mask[..., None] > 0, e, -1e9)
@@ -246,40 +239,15 @@ def mean_aggregate_padded(
     h_src: Optional[jax.Array], nbr: jax.Array, mask: jax.Array,
     hn: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Mean NA (RGCN). h_src [M, D] -> [N, D].  ``hn`` supplies the gather
-    pre-merged (async schedule's own/halo split) — same rows, same sum."""
+    """Mean NA (RGCN). h_src [M, D] -> [N, D].  ``hn``, when given, stands
+    for the gathered rows ``h_src[nbr]`` ``[N, K, D]``; the fault tests of
+    the benchmark (``bench/tests/test_bench_faults.py``) wrap this
+    signature."""
     if hn is None:
         hn = h_src[nbr]  # [N, K, D]
     s = (hn * mask[..., None]).sum(axis=1)
     d = jnp.maximum(mask.sum(axis=1, keepdims=True), 1.0)
     return s / d
-
-
-def gather_own(own: jax.Array, idx: jax.Array) -> jax.Array:
-    """Owned-side half of a split own/halo gather (see :func:`gather_merge`).
-
-    Indices pointing past the owned table clip to its last row; the merge
-    discards those lanes in favour of the halo side, so the clip value
-    never reaches the output.  Depends only on the owned table — the async
-    schedule dispatches it while the halo exchange is still in flight.
-    """
-    return own[jnp.clip(idx, 0, own.shape[0] - 1)]
-
-
-def gather_merge(
-    own_sel: jax.Array,  # gather_own(own, idx)
-    halo: jax.Array,  # [h_max, ...] exchanged halo rows (h_max may be 0)
-    idx: jax.Array,  # indices into the virtual concat([own, halo]) table
-    n_own: int,
-) -> jax.Array:
-    """Merge the split gather: ``concat([own, halo])[idx]`` as a where-select
-    of the two clipped row selections.  Pure row copies — bitwise equal to
-    gathering from the materialized concatenation."""
-    if halo.shape[0] == 0:
-        return own_sel
-    halo_sel = halo[jnp.clip(idx - n_own, 0, halo.shape[0] - 1)]
-    cond = (idx < n_own).reshape(idx.shape + (1,) * (halo.ndim - 1))
-    return jnp.where(cond, own_sel, halo_sel)
 
 
 def mean_aggregate_padded_sharded(

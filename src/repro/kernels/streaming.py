@@ -127,42 +127,3 @@ def pad_rows(x: jax.Array, multiple: int) -> jax.Array:
     if pad:
         x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
     return x
-
-
-class InflightWindow:
-    """Bounded async-dispatch window for the stage-graph schedule.
-
-    The host-side analogue of this module's double-buffered DMA loop: the
-    kernel keeps the DMA for chunk ``s+1`` in flight while chunk ``s``
-    reduces; the :meth:`~repro.core.pipeline.StageGraphExecutor.
-    forward_overlapped` driver keeps up to ``depth`` *stage results* in
-    flight on JAX's async dispatch stream while the host races ahead to
-    issue dependents.  Admitting one more stage past the window blocks on
-    the oldest (the DMA wait of slot ``s - depth``); ``depth <= 1`` is the
-    serial schedule — every admit blocks immediately, which is the bit-exact
-    parity baseline the tests pin.
-    """
-
-    def __init__(self, depth: int):
-        self.depth = max(int(depth), 1)
-        self._live: list = []
-        self.admitted: list = []
-        self.max_inflight = 0
-
-    def admit(self, name: str, value):
-        """Record ``value`` (a dispatched stage's output pytree) as in
-        flight; blocks until the window has room for it."""
-        self.admitted.append(name)
-        if self.depth <= 1:
-            jax.block_until_ready(value)
-            self.max_inflight = max(self.max_inflight, 1)
-            return value
-        self._live.append(value)
-        self.max_inflight = max(self.max_inflight, len(self._live))
-        while len(self._live) > self.depth:
-            jax.block_until_ready(self._live.pop(0))
-        return value
-
-    def drain(self) -> None:
-        while self._live:
-            jax.block_until_ready(self._live.pop(0))

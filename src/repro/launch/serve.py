@@ -107,15 +107,6 @@ def build_hgnn_infer(cfg: HGNNConfig, hg, mesh: Optional[Mesh] = None,
                         batch)
 
     if mesh is None:
-        # an async stage-graph schedule swaps the jitted monolith for the
-        # overlapped dispatcher (bit-exact; per-stage jits cached on the
-        # executor).  Sampled serving keeps the monolith — there the
-        # schedule's overlap source is the engine's sampler prefetch
-        # thread, and the serve engine diffs the jit cache for its
-        # compiles_after_warmup guarantee.
-        if plan.schedule is not None and plan.sample is None:
-            return BuiltHGNNInfer(model.executor.forward_overlapped, params,
-                                  batch, plan, model.executor)
         return BuiltHGNNInfer(jax.jit(model.forward), params, batch,
                               plan, model.executor)
 
@@ -204,10 +195,6 @@ def run_hgnn_serve(args, cfg: HGNNConfig, hg, built: BuiltHGNNInfer) -> None:
         # refusal, device OOM, sampler error): the run must not pass
         raise SystemExit(f"{rs['failed_requests']} of {len(reqs)} requests "
                          "FAILED with no --inject-faults schedule")
-    if "prefetch" in st:
-        pf = st["prefetch"]
-        print(f"  prefetch: issued={pf['issued']} hits={pf['hits']} "
-              f"mispredicts={pf['mispredicts']} cold={pf['cold']}")
     if "residency" in st:
         rd = st["residency"]
         print(f"  residency: cache_rows={rd['cache_rows']} "
@@ -246,8 +233,7 @@ def run_hgnn(args) -> None:
                      partitions=args.partitions,
                      layers=args.layers,
                      fanout=args.fanout,
-                     cache_rows=args.cache_rows,
-                     overlap=args.overlap)
+                     cache_rows=args.cache_rows)
     hg = make_dataset(args.dataset)
     mesh = None
     if args.mesh_data * args.mesh_model > 1:
@@ -275,17 +261,8 @@ def run_hgnn(args) -> None:
     print(f"{cfg.model}/{cfg.dataset} [na={na.kind}/{na.layout}"
           f"{' +fused-sa' if built.plan.sa.fuse_epilogue else ''}"
           f"{f' +partitions={part.k}' if part is not None else ''}"
-          f"{f' x{n_l}layers' if n_l > 1 else ''}"
-          f"{f' +overlap={cfg.overlap}' if built.plan.schedule else ''}] "
+          f"{f' x{n_l}layers' if n_l > 1 else ''}] "
           f"logits {logits.shape} on {mesh_desc}: {dt*1e3:.2f} ms/iter")
-    if built.plan.schedule is not None and mesh is None:
-        ov = built.executor.overlap_record()
-        d = built.executor.last_dispatch
-        print(f"  overlap: depth={ov['depth']} stages={ov['stages']} "
-              f"edges={ov['edges']} "
-              f"concurrent_pairs={ov['concurrent_pairs']} "
-              f"overlapped_stages={ov['overlapped_stages']} "
-              f"max_inflight={d.get('max_inflight', 1)}")
     res = (built.batch.get("residency")
            if isinstance(built.batch, dict) else None)
     if res is not None:
@@ -354,13 +331,6 @@ def main() -> None:
                          "from the cache section, partitioned runs skip the "
                          "halo exchange for hot rows, and serving keeps a "
                          "live per-type cache over the sampled frontier")
-    ap.add_argument("--overlap", type=int, default=0,
-                    help=">=1: async stage-graph schedule with that "
-                         "in-flight dispatch depth — halo exchange overlaps "
-                         "owned-rows NA, per-metapath NA stages dispatch "
-                         "concurrently, and serving prefetches the next "
-                         "step's sample while the device computes "
-                         "(1 = serial-degenerate parity baseline)")
     ap.add_argument("--fanout", type=int, default=0,
                     help=">=1: request-path serving — neighbor-sampled "
                          "minibatch inference (per-hop fan-out cap) through "

@@ -108,77 +108,6 @@ class HGNNRequest:
         return self.status in resilience.TERMINAL
 
 
-class _SamplerPrefetcher:
-    """Async host-side sampler refill — one of the stage-graph schedule's
-    three overlap sources (``ScheduleSpec.prefetch``).
-
-    While the device executes step ``t``'s jitted forward, a single worker
-    thread samples the *predicted* step ``t+1`` union batch
-    (``HGNNServeEngine._predict_next`` simulates the engine's own
-    slot/queue advance).  The prediction misses whenever the simulation is
-    wrong — deadline expiry, a degradation shift, a failed step — in which
-    case :meth:`take` discards the speculative batch and the engine falls
-    back to the synchronous sampler.  Always correct regardless of hit
-    rate: ``HGNNSampler.sample`` is a pure function of ``(ids, rung)``
-    (its RNG only seeds the one-time table build), so a discarded
-    speculative call perturbs nothing.
-    """
-
-    def __init__(self, sampler):
-        from concurrent.futures import ThreadPoolExecutor
-
-        self.sampler = sampler
-        self._pool = ThreadPoolExecutor(max_workers=1)
-        self._future = None
-        self._key = None
-        self.counters: Dict[str, int] = {
-            "issued": 0, "hits": 0, "mispredicts": 0, "cold": 0}
-
-    @staticmethod
-    def _mk_key(ids: np.ndarray, rung_limit: int):
-        return (np.asarray(ids, np.int64).tobytes(), int(rung_limit))
-
-    def submit(self, ids: np.ndarray, rung_limit: int) -> None:
-        """Start sampling a speculative next-step batch (at most one in
-        flight; a still-pending speculation keeps its slot)."""
-        if self._future is not None:
-            return
-        self._key = self._mk_key(ids, rung_limit)
-        self.counters["issued"] += 1
-        self._future = self._pool.submit(
-            self.sampler.sample, np.asarray(ids, np.int64),
-            max_rung=int(rung_limit))
-
-    def take(self, ids: np.ndarray, rung_limit: int):
-        """The prefetched batch iff it answers exactly ``(ids,
-        rung_limit)``; ``None`` (sync fallback) otherwise."""
-        fut, self._future = self._future, None
-        if fut is None:
-            self.counters["cold"] += 1
-            return None
-        try:
-            sb = fut.result()
-        except Exception:  # noqa: BLE001 — sync retry path re-raises it
-            sb = None
-        if sb is None or self._key != self._mk_key(ids, rung_limit):
-            self.counters["mispredicts"] += 1
-            return None
-        self.counters["hits"] += 1
-        return sb
-
-    def drain(self) -> None:
-        """Block on any in-flight speculation and stop the worker — serve
-        teardown must not leak a running sampler thread, whether the loop
-        ended clean, deadline-expired every request, or failed over."""
-        if self._future is not None:
-            try:
-                self._future.result()
-            except Exception:  # noqa: BLE001 — speculation is disposable
-                pass
-            self._future = None
-        self._pool.shutdown(wait=True)
-
-
 class HGNNServeEngine:
     """Slot-based continuous batching for HGNN requests.
 
@@ -267,11 +196,6 @@ class HGNNServeEngine:
         self.degrade = DegradationController(
             self.res, len(self.sampler.ladder), self.slot_targets)
         self.retry = RetryPolicy(self.res)
-        # async sampler refill rides the plan's stage-graph schedule — the
-        # host samples step t+1 while the device runs step t's forward
-        sched = self.plan.schedule
-        self.prefetch = (_SamplerPrefetcher(self.sampler)
-                         if sched is not None and sched.prefetch else None)
         self._deadline_expired = 0
         self._failovers = 0
         self._lost_partitions: List[int] = []
@@ -298,47 +222,6 @@ class HGNNServeEngine:
             from repro.dist.partition import partition_batch
             return partition_batch(self._serve_plan, batch)
         return batch
-
-    def _predict_next(self, active, q, chunks):
-        """Predict the NEXT step's ``(union ids, rung limit)`` by simulating
-        this step's completion: each chunk advances its request's cursor,
-        exhausted slots refill from the queue in slot order, and the
-        chunking re-runs under the *current* degradation level.  Purely
-        speculative — deadline expiry, a degradation shift or a failed step
-        falsifies it, and ``_SamplerPrefetcher.take`` then discards the
-        speculative batch (counted in ``mispredicts``).  Returns ``None``
-        when the simulation finds no next step."""
-        done = {id(r): start + len(cids) for r, start, cids in chunks}
-        qi = list(q)
-        qpos = 0
-        cursors = []
-        for r in active:
-            cur = None
-            if r is not None:
-                d = done.get(id(r), r._done)
-                if d < len(r._serve_ids):
-                    cur = (r, d)
-            if cur is None and qpos < len(qi):
-                cur = (qi[qpos], qi[qpos]._done)
-                qpos += 1
-            cursors.append(cur)
-        chunk = self.degrade.chunk()
-        rung_limit = self.degrade.rung_limit()
-        t_budget = self.sampler.ladder[rung_limit][0]
-        parts = []
-        n_union = 0
-        for cur in cursors:
-            if cur is None:
-                continue
-            if n_union >= t_budget:
-                break
-            r, d = cur
-            take = min(chunk, t_budget - n_union, len(r._serve_ids) - d)
-            parts.append(np.asarray(r._serve_ids[d: d + take], np.int64))
-            n_union += take
-        if not parts:
-            return None
-        return np.concatenate(parts), rung_limit
 
     def _maybe_failover(self, step: int) -> None:
         """Injected partition loss -> re-assign the lost partition's
@@ -418,8 +301,7 @@ class HGNNServeEngine:
                 inj = self.injector
                 try:
                     with span("hgnn.serve.sample", rec, "sample_s"):
-                        sb = self._sample(ids, rung_limit, step, active, q,
-                                          chunks)
+                        sb = self._sample(ids, rung_limit, step)
                     for k in SAMPLE_PARTS:
                         rec[k] = sb.meta[k]
                     with span("hgnn.forward", rec, "forward_s"):
@@ -496,8 +378,6 @@ class HGNNServeEngine:
                 self.step_log.append(rec)
                 self.last_sb = sb
                 step += 1
-        if self.prefetch is not None:
-            self.prefetch.drain()
         for r in requests:
             self._status_counts[r.status] = (
                 self._status_counts.get(r.status, 0) + 1)
@@ -552,28 +432,15 @@ class HGNNServeEngine:
             n_union += take
         return q, active, chunks, level_used, rung_limit
 
-    def _sample(self, ids, rung_limit, step, active, q, chunks):
+    def _sample(self, ids, rung_limit, step):
         """The step's sampled batch, under the retry policy and the fault
-        hook; then the next step's speculative sample, when prefetching."""
+        hook."""
         inj = self.injector
-        # prefetch hit: the speculative batch stands in for the sampler
-        # call but still runs under the SAME retry policy and fault
-        # hook, so injected sampler faults (and their counters) fire
-        # identically whether the batch was prefetched or sampled sync
-        sb_pre = (self.prefetch.take(ids, rung_limit)
-                  if self.prefetch is not None else None)
-        sample_call = ((lambda: sb_pre) if sb_pre is not None else
-                       (lambda: self.sampler.sample(
-                           ids, max_rung=rung_limit)))
-        sb = self.retry.run(
-            "sampler", sample_call,
+        return self.retry.run(
+            "sampler",
+            lambda: self.sampler.sample(ids, max_rung=rung_limit),
             hook=(lambda a: inj.check("sampler", step, a))
             if inj else None)
-        if self.prefetch is not None:
-            nxt = self._predict_next(active, q, chunks)
-            if nxt is not None:
-                self.prefetch.submit(*nxt)
-        return sb
 
     def stats(self) -> Dict:
         """Deterministic serving counters (walls reported, never gated).
@@ -621,9 +488,6 @@ class HGNNServeEngine:
                 "injected": inj_counts,
             },
         }
-        if self.prefetch is not None:
-            out["prefetch"] = {k: int(v)
-                               for k, v in self.prefetch.counters.items()}
         if self.caches is not None:
             hits = sum(c.hits for c in self.caches.values())
             misses = sum(c.misses for c in self.caches.values())

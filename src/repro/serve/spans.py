@@ -11,7 +11,7 @@ Span names, outermost first::
 
     hgnn.serve.step            one slot-loop step (arg ``step``)
       hgnn.serve.refill        expiry, refill, chunking
-      hgnn.serve.sample        the sampler call (retry, prefetch)
+      hgnn.serve.sample        the sampler call (retry)
         hgnn.sample            HGNNSampler.sample
           hgnn.sample.expand   frontier expansion, order, rung choice
           hgnn.sample.gather   local tables, relabel, feature rows

@@ -101,3 +101,25 @@ def tiny_cfg_base():
     return dict(n_layers=2, d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
                 vocab=101, dtype="float32", param_dtype="float32",
                 remat="full", attn_chunk=16)
+
+
+# The executor configurations the stage-walk tests run (tests/
+# test_stage_walk.py, tests/test_stage_pipeline.py): HAN stacked, bucketed
+# and csr; R-GCN; MAGNN; each at two layers; and the partitioned ones.
+WALK_MATRIX = [
+    ("han", {}),
+    ("han", {"degree_buckets": 3}),
+    ("han", {"fused": False}),
+    ("rgcn", {}),
+    ("magnn", {}),
+    ("han", {"layers": 2}),
+    ("rgcn", {"layers": 2}),
+    ("magnn", {"layers": 2}),
+    ("han", {"partitions": 4}),
+    ("rgcn", {"partitions": 4}),
+    ("magnn", {"partitions": 4}),
+    ("han", {"partitions": 4, "layers": 2}),
+    ("rgcn", {"partitions": 4, "layers": 2}),
+]
+WALK_IDS = [f"{m}-{'-'.join(f'{k}{v}' for k, v in kw.items()) or 'base'}"
+            for m, kw in WALK_MATRIX]

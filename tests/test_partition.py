@@ -7,6 +7,8 @@ to the same global endpoints, the halo index maps point at rows the owner
 actually populates, and the device-side ``gather_halo`` exchange fetches
 exactly the rows the maps name.
 """
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -15,6 +17,8 @@ from repro.configs.base import HGNNConfig
 from repro.core.models import get_model
 from repro.data.synthetic import DATASET_METAPATHS, DATASET_TARGET
 from repro.dist import partition as dp
+from repro.serve.engine import HGNNRequest, HGNNServeEngine
+from repro.serve.sampler import HGNNSampler
 
 
 def _tiny_tables():
@@ -238,3 +242,61 @@ def test_partition_batch_rejects_unsupported_layouts(tiny_hg):
 
     with pytest.raises(ValueError, match="no partitioned execution"):
         GCN(HGNNConfig(model="gcn", dataset="reddit", partitions=2)).plan()
+
+
+# ---------------------------------------------------------------------------
+# static partition shapes (what partitioned serving runs)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("model,kw", [
+    ("han", {}), ("rgcn", {}), ("magnn", {}), ("rgcn", {"layers": 2}),
+    ("han", {"layers": 2}),
+], ids=["han", "rgcn", "magnn", "rgcn-L2", "han-L2"])
+def test_static_partition_shapes_are_bitexact(tiny_hg, model, kw):
+    """static_shapes pads every per-type table to assignment-independent
+    capacities (n_max=ceil(n/k), h_max=n).  Pad rows are masked dead weight:
+    the forward over the padded batch must be BIT-EXACT the dynamic one."""
+    cfg = _cfg(model, fused=True, partitions=4, **kw)
+    m = get_model(cfg)
+    b_dyn = m.prepare(tiny_hg)
+    params = m.init(jax.random.key(0), b_dyn)
+    out_dyn = np.asarray(jax.jit(m.forward)(params, b_dyn))
+    plan = m.plan()
+    plan_s = dataclasses.replace(
+        plan, partition=dataclasses.replace(plan.partition,
+                                            static_shapes=True))
+    b_raw = get_model(_cfg(model, fused=True, **kw)).prepare(tiny_hg)
+    b_stat = dp.partition_batch(plan_s, b_raw)
+    out_stat = np.asarray(jax.jit(m.forward)(params, b_stat))
+    np.testing.assert_array_equal(out_dyn, out_stat)
+    # the capacities are assignment-independent: ceil(40/4) target rows
+    # per partition, halo capped at the type count
+    t = plan.target
+    assert b_stat["part"]["own"][t].shape == (4, 10)
+    assert b_stat["part"]["halo_src"][t].shape == (4, 40)
+
+
+def _mixed_requests(n, n_nodes=40, seed=3):
+    rng = np.random.default_rng(seed)
+    return [HGNNRequest(targets=rng.integers(
+        0, n_nodes, size=int(rng.integers(1, 9)))) for _ in range(n)]
+
+
+def test_partitioned_sampled_serving_zero_recompiles(tiny_hg):
+    """Partitioned sampled serving used to re-trace every step
+    (data-dependent halo widths).  With the engine's static_shapes serve
+    plan the warmed ladder covers every step."""
+    for model in ("han", "rgcn", "magnn"):
+        cfg = _cfg(model, fused=True, fanout=8, partitions=4)
+        m = get_model(cfg)
+        sampler = HGNNSampler(m.plan(), cfg, tiny_hg)
+        batch = m.prepare(tiny_hg)
+        params = m.init(jax.random.key(0), batch)
+        eng = HGNNServeEngine(m.executor, params, sampler, slots=4,
+                              slot_targets=2)
+        eng.warmup()
+        eng.serve(_mixed_requests(10))
+        st = eng.stats()
+        assert st["steps"] > 1
+        assert st["compiles_after_warmup"] == 0, model

@@ -118,10 +118,10 @@ def test_request_timeline_joins_step_records(tiny_hg, model):
     assert [r.seq for r in first + second] == list(range(12))
 
 
-def _scopes_expected(executor):
+def _scopes_expected(executor, params, batch):
     plan = executor.plan
     out = set()
-    for name in executor.schedule_edges():
+    for name in executor.stage_fns(params, batch):
         m = re.match(r"L(\d+)\.FP$", name)
         if m and plan.layers[int(m.group(1)) - 1].fp.kind == "identity":
             continue  # an identity FP (R-GCN's hidden layers) emits no op
@@ -141,6 +141,6 @@ def test_stage_scopes_reach_the_lowered_program(tiny_hg, model, layers,
     params = m.init(jax.random.key(0), batch)
     text = jax.jit(m.forward).lower(params, batch).as_text(debug_info=True)
     found = set(re.findall(r"jit\(forward\)/([A-Za-z0-9_.]+)", text))
-    want = _scopes_expected(m.executor)
+    want = _scopes_expected(m.executor, params, batch)
     assert ("gather_halo" in want) == bool(partitions)
     assert want <= found, want - found
